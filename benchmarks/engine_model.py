@@ -10,7 +10,7 @@ import numpy as np
 
 from repro.core.controller import CycleModel, run_gemv
 from repro.core.latency_model import IMAGINE_FSYS_MHZ, U55
-from repro.roofline.analysis import HW_V5E
+from repro.roofline.analysis import V5E, peaks
 
 
 def run():
@@ -36,7 +36,7 @@ def run():
     elems = -(-dim * dim // pes)
     fpga_cycles = elems * cm.mac() + cm.accum(32) + dim
     fpga_us = fpga_cycles / IMAGINE_FSYS_MHZ
-    tpu_us = (dim * dim * 1) / HW_V5E["hbm_bw"] * 1e6  # int8 weights, 1B/w
+    tpu_us = (dim * dim * 1) / peaks(V5E)["hbm_bw"] * 1e6  # int8 weights, 1B/w
     rows.append(("engine.u55_vs_v5e_gemv.d1967", round(fpga_us, 1),
                  f"fpga_cycles={fpga_cycles}"
                  f" v5e_hbm_bound_us={tpu_us:.2f}"

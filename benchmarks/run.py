@@ -46,7 +46,9 @@ def main() -> None:
         "costs": costs_bench.run,
     }
     from benchmarks.common import bench_env
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     env = bench_env()
     print(f"# device_kind={env['device_kind']}  "
           f"interpret_mode={env['interpret_mode']}")

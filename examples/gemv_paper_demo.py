@@ -20,9 +20,11 @@ from repro.core.controller import run_gemv
 from repro.core.isa import assemble_gemv, roundtrip
 from repro.core.latency_model import FIG6_DESIGNS, IMAGINE_FSYS_MHZ
 from repro.engine import EnginePlan, pack_linear
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--dim", type=int, default=96)
     args = ap.parse_args()

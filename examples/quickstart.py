@@ -13,6 +13,7 @@ import jax.numpy as jnp
 from repro.config import get_reduced
 from repro.config.base import EngineConfig, TrainConfig
 from repro.data import DataPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import (
     decode_step,
     forward,
@@ -25,6 +26,7 @@ from repro.train.trainer import make_train_step
 
 
 def main():
+    enable_compile_cache()
     cfg = dataclasses.replace(get_reduced("gemma3-27b"), dtype="float32")
     print(f"arch family={cfg.family} layers={cfg.n_layers} "
           f"d_model={cfg.d_model} params={cfg.param_count():,}")

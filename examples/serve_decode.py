@@ -20,6 +20,7 @@ import repro.obs as obs
 from repro.config import get_reduced
 from repro.config.base import EngineConfig, ServeConfig, TrainConfig
 from repro.data import DataPipeline
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.serve import ServeEngine, ServeFrontend
 from repro.train import Trainer
@@ -31,6 +32,7 @@ def tree_bytes(t):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--tokens", type=int, default=12)
     ap.add_argument("--reqs", type=int, default=6)

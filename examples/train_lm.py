@@ -21,11 +21,13 @@ from repro.config import get_reduced
 from repro.config.base import TrainConfig
 from repro.data import DataPipeline
 from repro.ft import FailureInjector, StragglerMonitor
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.train import Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2.5-3b")
     ap.add_argument("--steps", type=int, default=60)

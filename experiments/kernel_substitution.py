@@ -18,7 +18,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.config import SHAPES, get_arch          # noqa: E402
-from repro.roofline.analysis import HW_V5E          # noqa: E402
+from repro.roofline.analysis import V5E, peaks          # noqa: E402
 
 PASSES = {"train": 3.0, "prefill": 1.0, "decode": 1.0}
 
@@ -67,8 +67,8 @@ def main():
         new = calc(cfg, shape, n_dev)
         subs[region] = (old, new)
         new_total = new_total - old + new
-    mem_old = total / HW_V5E["hbm_bw"]
-    mem_new = new_total / HW_V5E["hbm_bw"]
+    mem_old = total / peaks(V5E)["hbm_bw"]
+    mem_new = new_total / peaks(V5E)["hbm_bw"]
     print(f"cell: {r['arch']} x {r['shape']} ({r.get('tag') or 'baseline'})")
     for region, (old, new) in subs.items():
         print(f"  {region:10s}: {old/1e12:8.3f} TB/dev  ->  {new/1e12:8.4f} TB/dev"

@@ -28,44 +28,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-try:
-    import zstandard
-except ImportError:  # container image may not ship zstandard
-    zstandard = None
-
-
-class _NullCompressor:
-    """Identity codec used when zstandard is unavailable.
-
-    Shards are written raw (bigger on disk, same manifest/checksum
-    integrity); ``codec`` is recorded in the manifest so a zstd-equipped
-    reader still decodes both formats.
-    """
-
-    def compress(self, data: bytes) -> bytes:
-        return data
-
-    def decompress(self, data: bytes) -> bytes:
-        return data
-
-
-def _compressor():
-    return (zstandard.ZstdCompressor(level=3) if zstandard is not None
-            else _NullCompressor())
-
-
-def _decompressor(codec: str):
-    if codec == "zstd":
-        if zstandard is None:
-            raise ImportError(
-                "checkpoint was written zstd-compressed but zstandard is "
-                "not installed")
-        return zstandard.ZstdDecompressor()
-    if codec == "raw":
-        return _NullCompressor()
-    raise ValueError(f"unknown checkpoint codec {codec!r} "
-                     "(expected 'zstd' or 'raw')")
-
+import zstandard
 
 Pytree = Any
 
@@ -96,9 +59,9 @@ def save_checkpoint(
     os.makedirs(tmp, exist_ok=True)
 
     manifest = {"magic": _MAGIC, "step": step, "n_hosts": n_hosts,
-                "codec": "zstd" if zstandard is not None else "raw",
+                "codec": "zstd",
                 "extra": extra or {}, "leaves": []}
-    cctx = _compressor()
+    cctx = zstandard.ZstdCompressor(level=3)
     blob = bytearray()
     for i, (p, leaf) in enumerate(zip(paths, leaves)):
         if i % n_hosts != host_id:
@@ -169,7 +132,10 @@ def load_checkpoint(
 
     blobs = {}
     for h in manifests:
-        dctx = _decompressor(manifests[h].get("codec", "zstd"))
+        codec = manifests[h].get("codec", "zstd")
+        if codec != "zstd":
+            raise ValueError(f"unknown checkpoint codec {codec!r}")
+        dctx = zstandard.ZstdDecompressor()
         with open(os.path.join(final, f"shard_{h}.bin"), "rb") as f:
             blobs[h] = dctx.decompress(f.read())
 

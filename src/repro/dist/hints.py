@@ -1,7 +1,7 @@
 """Sharding hints: best-effort ``with_sharding_constraint`` wrappers.
 
 Model code calls these unconditionally; they are no-ops unless a mesh
-context is active (``with mesh:``), and they silently drop any axis name
+context is active (:func:`use_mesh`), and they silently drop any axis name
 the active mesh does not have or that does not divide the corresponding
 array dimension.  That lets one model implementation run unchanged on a
 single CPU device, an 8-device host test mesh, and the 512-chip dry-run.
@@ -19,56 +19,25 @@ AxisEntry = Union[None, str, Sequence[str]]
 
 
 def active_mesh():
-    """The mesh entered via :func:`use_mesh` / ``with mesh:``, or None.
-
-    Checks both mesh-context mechanisms: ``jax.sharding.set_mesh`` (newer
-    jax — :func:`use_mesh` prefers it when present, and it does NOT
-    populate the legacy thread-resources slot) and the legacy ``with
-    mesh:`` context.  Missing either would silently turn every sharding
-    hint into a no-op on one side of the version boundary.
-    """
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        try:
-            m = get_abstract()
-            if m is not None and getattr(m, "axis_names", ()):
-                return m
-        except Exception:
-            pass
-    try:
-        from jax._src.mesh import thread_resources
-
-        m = thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
+    """The mesh entered via :func:`use_mesh`, or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.axis_names else None
 
 
 def use_mesh(mesh):
-    """Version-portable mesh context manager.
-
-    Newer jax spells this ``jax.sharding.set_mesh``; on older releases the
-    ``Mesh`` object itself is the context manager.  Model-internal sharding
-    hints (:func:`with_hint`) only fire inside this context.
-    """
-    set_mesh = getattr(jax.sharding, "set_mesh", None)
-    if set_mesh is not None:
-        return set_mesh(mesh)
-    return mesh
+    """Mesh context manager (``jax.sharding.set_mesh``).  Model-internal
+    sharding hints (:func:`with_hint`) only fire inside it."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def make_mesh(shape, axis_names):
-    """Version-portable ``jax.make_mesh`` with Auto axis types when the
-    installed jax supports explicit axis typing."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(
-                shape, axis_names,
-                axis_types=(axis_type.Auto,) * len(axis_names))
-        except TypeError:
-            pass
-    return jax.make_mesh(shape, axis_names)
+    """``jax.make_mesh`` with ``Auto`` axes: the compiler propagates
+    shardings from the placed arrays and the hints, as every caller here
+    expects (``jax.make_mesh``'s default ``Explicit`` axes would demand a
+    sharding on every intermediate)."""
+    return jax.make_mesh(
+        shape, axis_names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names))
 
 
 def _filter_entry(entry: AxisEntry, dim: int, axes: dict) -> AxisEntry:

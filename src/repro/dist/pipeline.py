@@ -18,7 +18,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.hints import active_mesh
@@ -100,10 +99,10 @@ def pipeline_apply(
         # only the last stage holds real outputs; psum replicates them
         return jax.lax.psum(outputs, axis_name)
 
-    return shard_map(
+    return jax.shard_map(
         ranked,
         mesh=mesh,
         in_specs=(P(axis_name), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(staged_params, micros)

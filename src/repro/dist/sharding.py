@@ -31,10 +31,10 @@ _SPECIAL = {"embed"}
 # engine PackedLinear leaves ride the rules of their owning linear.
 _ENGINE_LEAVES = {"packed", "scale", "bias", "w"}
 
-# decode-cache / page-pool leaves, by key name.  The stacked slot cache
-# (L, B, T, Hkv, Dh) and the KVPages pool (L, P, page, Hkv, Dh) share one
-# rule set: axis 1 (batch lanes or physical pages) shards over the data
-# axes, the KV-head axis over ``model``.
+# decode-cache leaves, by key name.  The stacked slot cache
+# (L, B, T, Hkv, Dh) shards axis 1 (batch lanes) over the data axes and the
+# KV-head axis over ``model``; the head-major KVPages pool has its own rule
+# (``_pool_shardings``).
 _ATTN_KV_KEYS = {"k", "v", "k_global", "v_global", "k_local", "v_local"}
 # int8-cache / quantized-page scale pools: trailing axis is the KV-head
 # axis and must follow its K/V pool's head sharding.
@@ -194,12 +194,14 @@ def cache_shardings(mesh, cache: Pytree) -> Pytree:
 
     Handles the stacked ``(L, B, ...)`` layout, the unstacked
     tuple-of-``(B, ...)`` production layout, *and* the paged-serving
-    :class:`~repro.serve.pages.KVPages` pytree: its ``(L, P, page, Hkv,
-    Dh)`` pools shard pages-over-data and heads-over-``model``, its scale
-    pools follow their K/V pool's head sharding on the trailing axis, and
+    :class:`~repro.serve.pages.KVPages` pytree: its head-major ``(L, Hkv,
+    P, page, Dh)`` pools shard heads-over-``model`` and pages-over-data,
+    its ``(L, Hkv, P, page)`` scale pools follow their K/V pool, and
     block tables / positions / activity masks shard their lane axis over
     the data axes only (they are host-built index state).
     """
+    if hasattr(cache, "page_size"):  # a KVPages pool
+        return _pool_shardings(mesh, cache)
     data_axes = _data_axes(mesh)
     sizes = _mesh_sizes(mesh)
     msize = sizes.get("model", 0)
@@ -210,8 +212,8 @@ def cache_shardings(mesh, cache: Pytree) -> Pytree:
         if ndim == 0:
             return NamedSharding(mesh, P())
         names = [_key_str(k) for k in path]
-        # innermost cache-key name wins, so a KVPages (or cache dict)
-        # nested inside a bigger serve-state tree keeps its rules.
+        # innermost cache-key name wins, so a cache dict nested inside a
+        # bigger serve-state tree keeps its rules.
         name = next(
             (n for n in reversed(names) if n in _STACKED_CACHE_KEYS),
             names[-1] if names else "")
@@ -233,6 +235,25 @@ def cache_shardings(mesh, cache: Pytree) -> Pytree:
         return NamedSharding(mesh, P(*spec))
 
     return jax.tree_util.tree_map_with_path(one, cache)
+
+
+def _pool_shardings(mesh, pages) -> Pytree:
+    """KVPages shardings: axis 1 (KV heads) over ``model`` when it
+    divides, axis 2 (physical pages) over the data axes — K/V pools and
+    their scale pools alike, so dequantization never desyncs."""
+    sizes = _mesh_sizes(mesh)
+    msize = sizes.get("model", 0)
+
+    def one(leaf):
+        spec = [None] * leaf.ndim
+        if msize and leaf.shape[1] % msize == 0:
+            spec[1] = "model"
+        kept = _divisible_prefix(leaf.shape[2], _data_axes(mesh), sizes)
+        if kept:
+            spec[2] = kept
+        return NamedSharding(mesh, P(*spec))
+
+    return jax.tree.map(one, pages)
 
 
 def paged_attn_partition(mesh, model_axis: str, n_kv_heads: int,

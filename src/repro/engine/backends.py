@@ -26,6 +26,7 @@ TPU hosts get ``pallas_tpu``, everything else gets ``reference``.
 
 from __future__ import annotations
 
+import warnings
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -203,10 +204,17 @@ def _pallas(plan, lin: PackedLinear, x: jnp.ndarray, out_dtype,
             interpret: bool):
     from repro.kernels.bitplane_gemv.ops import bitplane_gemv
 
-    if lin.packed.ndim != 2 or x.ndim > 2:
-        # stacked experts / batched-seq activations: the kernel is a 2D
-        # GEMV tile engine; fall back to the exact jnp path.
+    if lin.packed.ndim != 2:
+        # stacked (E, Kp, N) expert weights: the kernel is a 2-D GEMV tile
+        # engine and has no expert grid axis yet, so these alone take the
+        # exact jnp path — said out loud, once per traced shape.
+        warnings.warn(
+            f"bit-plane kernel: stacked weight {lin.packed.shape} runs on "
+            "the jnp reference backend (the kernel handles 2-D weights "
+            "only)", stacklevel=2)
         return _reference(plan, lin, x, out_dtype)
+    # (B, D) decode rows and (B, C, D) prefill chunks alike: the wrapper
+    # flattens leading activation dims onto the kernel's batch axis
     return bitplane_gemv(
         lin.packed, lin.scale, x,
         bits=lin.bits, radix=plan.radix,

@@ -30,21 +30,14 @@ this on an 8-device host mesh).
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.collectives import psum_partial
 from repro.dist.sharding import paged_attn_partition
 from repro.engine.backends import get_backend, register_backend
 from repro.engine.packed import PackedLinear, partition_kind
-
-
-def _mesh_axis_size(mesh, axis: str) -> int:
-    try:
-        return dict(mesh.shape).get(axis, 1)
-    except Exception:
-        return 1
 
 
 def _batch_entry(mesh, model_axis: str, x: jnp.ndarray):
@@ -71,7 +64,7 @@ def _batch_entry(mesh, model_axis: str, x: jnp.ndarray):
 def _sharded(plan, lin: PackedLinear, x: jnp.ndarray, out_dtype):
     inner = get_backend(plan.inner_backend or "reference")
     mesh, axis = plan.mesh, plan.model_axis
-    msize = _mesh_axis_size(mesh, axis) if mesh is not None else 1
+    msize = dict(mesh.shape).get(axis, 1) if mesh is not None else 1
     kind = partition_kind(lin, msize)
     if mesh is None or kind == "replicate":
         return inner(plan, lin, x, out_dtype)
@@ -86,11 +79,11 @@ def _sharded(plan, lin: PackedLinear, x: jnp.ndarray, out_dtype):
             loc = PackedLinear(packed, scale, None, bits, k, n // msize)
             return inner(plan, loc, xx, out_dtype)
 
-        return shard_map(
+        return jax.shard_map(
             col, mesh=mesh,
             in_specs=(P(None, axis), P(None, axis), P(*lead, None)),
             out_specs=P(*lead, axis),
-            check_rep=False,
+            check_vma=False,
         )(lin.packed, lin.scale, x)
 
     # row-parallel: K (packed rows + activation features) over the model
@@ -101,11 +94,11 @@ def _sharded(plan, lin: PackedLinear, x: jnp.ndarray, out_dtype):
         return psum_partial(part, axis, bits=plan.psum_bits).astype(
             out_dtype)
 
-    return shard_map(
+    return jax.shard_map(
         row, mesh=mesh,
         in_specs=(P(axis, None), P(None, None), P(*lead, axis)),
         out_specs=P(*lead, None),
-        check_rep=False,
+        check_vma=False,
     )(lin.packed, lin.scale, x)
 
 
@@ -146,11 +139,11 @@ def sharded_paged_attention(mesh, model_axis, qg, k_pages, v_pages,
     )
 
     head, lane = paged_attn_partition(
-        mesh, model_axis, k_pages.shape[2], qg.shape[0])
+        mesh, model_axis, k_pages.shape[0], qg.shape[0])
     q_tail = (None,) * (qg.ndim - 2)
     q_spec = P(lane, head, *q_tail)
-    pool = P(None, None, head, None)
-    scale_p = P(None, None, head)
+    pool = P(head, None, None, None)      # head-major (Hkv, P, page, Dh)
+    scale_p = P(head, None, None)         # (Hkv, P, page)
     bt_s, lane_s, win_s = P(lane, None), P(lane), P(None)
     quant = k_scale is not None
 
@@ -179,5 +172,5 @@ def sharded_paged_attention(mesh, model_axis, qg, k_pages, v_pages,
         in_specs = in_specs + (scale_p, scale_p)
         operands = operands + (k_scale, v_scale)
 
-    return shard_map(run, mesh=mesh, in_specs=in_specs, out_specs=q_spec,
-                     check_rep=False)(*operands)
+    return jax.shard_map(run, mesh=mesh, in_specs=in_specs,
+                         out_specs=q_spec, check_vma=False)(*operands)

@@ -42,20 +42,19 @@ def _kernel(packed_ref, scale_ref, x_ref, o_ref, *, bits: int, radix: int,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     per_byte = 8 // bits
-    words = packed_ref[...].astype(jnp.uint8)  # (block_k/per_byte, block_n)
+    # widen to 32-bit lanes before any shift: the TPU's vector unit has no
+    # 8-bit shifts, so sub-byte unpacking must happen on int32 words
+    words = packed_ref[...].astype(jnp.int32) & 0xFF  # (block_k/per_byte, N)
 
     if per_byte > 1:
         # unpack the packed K axis in-register (VREG shift/mask), restoring
         # K-major order: element k = i*per_byte + s lives in word i, digit s.
         mask = (1 << bits) - 1
-        digs = [
-            ((words >> (s * bits)) & mask).astype(jnp.uint8)
-            for s in range(per_byte)
-        ]
+        digs = [(words >> (s * bits)) & mask for s in range(per_byte)]
         stacked = jnp.stack(digs, axis=1)  # (words_k, per_byte, block_n)
-        code = stacked.reshape(block_k, words.shape[-1])
+        code_i32 = stacked.reshape(block_k, words.shape[-1])
     else:
-        code = words  # (block_k, block_n) two's-complement codes
+        code_i32 = words  # (block_k, block_n) two's-complement codes
 
     x = x_ref[...].astype(jnp.float32)
     acc = jnp.zeros((x.shape[0], words.shape[-1]), jnp.float32)
@@ -63,7 +62,6 @@ def _kernel(packed_ref, scale_ref, x_ref, o_ref, *, bits: int, radix: int,
     # --- the bit-serial east->west passes (static unroll over digits) ------
     n_digits = bits // radix
     digit_mask = (1 << radix) - 1
-    code_i32 = code.astype(jnp.int32)
     for d in range(n_digits):
         digit = (code_i32 >> (d * radix)) & digit_mask
         if d == n_digits - 1:
@@ -137,4 +135,5 @@ def bitplane_gemv_pallas(
         out_specs=pl.BlockSpec((block_b, block_n), lambda bb, j, kk: (bb, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
         interpret=interpret,
+        name="bitplane_gemv",
     )(packed, scale, x).astype(out_dtype)

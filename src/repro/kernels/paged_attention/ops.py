@@ -2,7 +2,8 @@
 chunked prefill).
 
 Accept the model layouts (decode ``q`` as ``(B, 1, Hq, Dh)``, prefill
-``q`` as ``(B, C, Hq, Dh)``, pools as ``(P, page, Hkv, Dh)``) plus an
+``q`` as ``(B, C, Hq, Dh)``, head-major pools as ``(Hkv, P, page, Dh)``,
+scale pools as ``(Hkv, P, page)``) plus an
 *attention backend name* — models/, serve/ and benchmarks/ never decide
 interpret booleans themselves (the EnginePlan hygiene rule); the name →
 interpret mapping lives here, next to the kernel.  A ``mesh`` routes the
@@ -38,15 +39,15 @@ PREFILL_BLOCK_Q = 128  # cap on query rows per prefill grid step
 
 def paged_attention(
     q: jnp.ndarray,            # (B, 1, Hq, Dh) — model decode layout
-    k_pages: jnp.ndarray,      # (P, page, Hkv, Dh)
+    k_pages: jnp.ndarray,      # (Hkv, P, page, Dh)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     cur_pos: jnp.ndarray,      # (B,)
     window=0,                  # python int or traced scalar; <= 0 = full
-    k_scale: Optional[jnp.ndarray] = None,  # (P, page, Hkv) int8 pools only
+    k_scale: Optional[jnp.ndarray] = None,  # (Hkv, P, page) int8 pools only
     v_scale: Optional[jnp.ndarray] = None,
     *,
-    attn_backend: str = "pallas_interpret",
+    attn_backend: str,
     mesh=None,
     model_axis: str = "model",
 ) -> jnp.ndarray:
@@ -63,7 +64,7 @@ def paged_attention(
             f"paged_attention runs the fused kernel only "
             f"(pallas_interpret/pallas_tpu); got {attn_backend!r}")
     b, _, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[0]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
     win = jnp.asarray(window, jnp.int32).reshape(1)
@@ -83,7 +84,7 @@ def paged_attention(
 
 def paged_prefill_attention(
     q: jnp.ndarray,            # (B, C, Hq, Dh) — model prefill layout
-    k_pages: jnp.ndarray,      # (P, page, Hkv, Dh)
+    k_pages: jnp.ndarray,      # (Hkv, P, page, Dh)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     pos0: jnp.ndarray,         # (B,) tokens already resident per lane
@@ -92,7 +93,7 @@ def paged_prefill_attention(
     k_scale: Optional[jnp.ndarray] = None,
     v_scale: Optional[jnp.ndarray] = None,
     *,
-    attn_backend: str = "pallas_interpret",
+    attn_backend: str,
     mesh=None,
     model_axis: str = "model",
 ) -> jnp.ndarray:
@@ -111,7 +112,7 @@ def paged_prefill_attention(
             f"paged_prefill_attention runs the fused kernel only "
             f"(pallas_interpret/pallas_tpu); got {attn_backend!r}")
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[0]
     g = hq // hkv
     block_q = min(c, PREFILL_BLOCK_Q)
     cp = -(-c // block_q) * block_q
@@ -143,24 +144,24 @@ def synthetic_paged_case(rng, *, batch: int, nblk: int, page: int,
     ``benchmarks/kernel_bench.py``, so both benches measure identical
     inputs.  ``rng``: a ``numpy.random.Generator``.  Returns a dict with
     ``q / k_pages / v_pages / k_scale / v_scale / block_tables``
-    (scales None unless ``kv_bits``); block tables are a permutation of
-    ``batch * nblk`` distinct non-null pages."""
+    (head-major pools; scales None unless ``kv_bits``); block tables are
+    a permutation of ``batch * nblk`` distinct non-null pages."""
     import numpy as np
 
     n_pages = batch * nblk + 1
     if kv_bits:
-        kp = jnp.asarray(rng.integers(-127, 128, (n_pages, page, hkv, dh)),
+        kp = jnp.asarray(rng.integers(-127, 128, (hkv, n_pages, page, dh)),
                          jnp.int8)
-        vp = jnp.asarray(rng.integers(-127, 128, (n_pages, page, hkv, dh)),
+        vp = jnp.asarray(rng.integers(-127, 128, (hkv, n_pages, page, dh)),
                          jnp.int8)
-        ks = jnp.asarray(rng.uniform(0.004, 0.02, (n_pages, page, hkv)),
+        ks = jnp.asarray(rng.uniform(0.004, 0.02, (hkv, n_pages, page)),
                          jnp.bfloat16)
-        vs = jnp.asarray(rng.uniform(0.004, 0.02, (n_pages, page, hkv)),
+        vs = jnp.asarray(rng.uniform(0.004, 0.02, (hkv, n_pages, page)),
                          jnp.bfloat16)
     else:
-        kp = jnp.asarray(rng.standard_normal((n_pages, page, hkv, dh))
+        kp = jnp.asarray(rng.standard_normal((hkv, n_pages, page, dh))
                          .astype(np.float32))
-        vp = jnp.asarray(rng.standard_normal((n_pages, page, hkv, dh))
+        vp = jnp.asarray(rng.standard_normal((hkv, n_pages, page, dh))
                          .astype(np.float32))
         ks = vs = None
     return {

@@ -19,14 +19,16 @@ NEG_INF = -1e30
 
 
 def _gather(pages: jnp.ndarray, block_tables: jnp.ndarray) -> jnp.ndarray:
-    g = jnp.take(pages, block_tables, axis=0)       # (B, nblk, page, ...)
-    b, nblk, page = g.shape[:3]
-    return g.reshape((b, nblk * page) + g.shape[3:])
+    """Head-major ``(Hkv, P, page, ...)`` pool -> ``(B, T, Hkv, ...)``."""
+    g = jnp.take(pages, block_tables, axis=1)       # (Hkv, B, nblk, page, ...)
+    hkv, b, nblk, page = g.shape[:4]
+    g = g.reshape((hkv, b, nblk * page) + g.shape[4:])
+    return jnp.moveaxis(g, 0, 2)
 
 
 def paged_attention_ref(
     q: jnp.ndarray,            # (B, 1, Hq, Dh)
-    k_pages: jnp.ndarray,      # (P, page, Hkv, Dh)
+    k_pages: jnp.ndarray,      # (Hkv, P, page, Dh)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     cur_pos: jnp.ndarray,      # (B,)
@@ -35,7 +37,7 @@ def paged_attention_ref(
     v_scale: Optional[jnp.ndarray] = None,
 ) -> jnp.ndarray:
     b, _, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[0]
     g = hq // hkv
     scale = d ** -0.5
     kg = _gather(k_pages, block_tables)             # (B, T, Hkv, Dh)
@@ -66,7 +68,7 @@ def paged_attention_ref(
 
 def paged_prefill_ref(
     q: jnp.ndarray,            # (B, C, Hq, Dh) — one prefill chunk
-    k_pages: jnp.ndarray,      # (P, page, Hkv, Dh)
+    k_pages: jnp.ndarray,      # (Hkv, P, page, Dh)
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     pos0: jnp.ndarray,         # (B,) tokens already resident
@@ -79,7 +81,7 @@ def paged_prefill_ref(
     ``attend_paged_prefill`` gather path (causal over logical positions,
     KV clipped to ``min(seq_lens, pos0 + C)``)."""
     b, c, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[0]
     g = hq // hkv
     scale = d ** -0.5
     kg = _gather(k_pages, block_tables)             # (B, T, Hkv, Dh)

@@ -3,6 +3,8 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("XLA_FLAGS", "")
 )
+# the dry-run lowers on 512 virtual host devices and never needs the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run driver.
 
@@ -11,8 +13,9 @@ and compile the cell's step function on the production mesh, print
 ``memory_analysis()`` and ``cost_analysis()``, derive the three roofline
 terms, and append the record to a JSON results file.
 
-The two lines above run before ANY other import (jax locks the device count
-at first init): the dry-run — and only the dry-run — sees 512 host devices.
+The lines above run before ANY other import (jax locks the device count
+and platform at first init): the dry-run — and only the dry-run — sees 512
+host devices, and it stays off any accelerator the host has.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch gemma3-27b \
@@ -45,7 +48,7 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
                                    ServeConfig, TrainConfig)
     from repro.launch.mesh import make_production_mesh
     from repro.launch.steps import build_cell
-    from repro.roofline.analysis import (model_bytes_for_cell,
+    from repro.roofline.analysis import (V5E, model_bytes_for_cell,
                                          model_flops_for_cell,
                                          roofline_report)
 
@@ -111,8 +114,9 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             leaves = jax.tree.leaves(cache_abs)
         cache_bytes = float(sum(
             np.prod(l.shape) * l.dtype.itemsize for l in leaves))
+    # the production mesh is a v5e pod: its terms use the v5e peaks
     report = roofline_report(
-        compiled, n_dev,
+        compiled, n_dev, device_kind=V5E,
         model_flops=model_flops_for_cell(cfg, shape),
         model_bytes=model_bytes_for_cell(cfg, shape, engine_bits,
                                          cache_bytes))

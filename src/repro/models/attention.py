@@ -334,8 +334,8 @@ def attend_decode_quant(
 
 
 # ---------------------------------------------------------------------------
-# paged-KV reads: K/V live in a shared (P, page, Hkv, D) page pool and are
-# addressed per request through a (B, n_blocks) block table.
+# paged-KV reads: K/V live in a shared head-major (Hkv, P, page, D) page
+# pool and are addressed per request through a (B, n_blocks) block table.
 # ---------------------------------------------------------------------------
 
 
@@ -343,15 +343,15 @@ def gather_kv_pages(pages: jnp.ndarray,
                     block_tables: jnp.ndarray) -> jnp.ndarray:
     """Materialize each lane's logical KV view from the page pool.
 
-    ``pages``: ``(P, page, ...)`` physical pool (one layer of K, V or a
-    scale pool); ``block_tables``: ``(B, n_blocks)`` int32 physical page
-    ids in logical order.  Returns ``(B, n_blocks * page, ...)`` — logical
-    position ``t`` of lane ``b`` lives at
-    ``pages[block_tables[b, t // page], t % page]``.
+    ``pages``: head-major ``(Hkv, P, page, ...)`` physical pool (one layer
+    of K, V or a scale pool); ``block_tables``: ``(B, n_blocks)`` int32
+    physical page ids in logical order.  Returns ``(B, n_blocks * page,
+    Hkv, ...)`` — logical position ``t`` of lane ``b``, head ``h`` lives at
+    ``pages[h, block_tables[b, t // page], t % page]``.
     """
-    g = jnp.take(pages, block_tables, axis=0)      # (B, nblk, page, ...)
-    b, nblk, page = g.shape[:3]
-    out = g.reshape((b, nblk * page) + g.shape[3:])
+    g = jnp.take(pages, block_tables, axis=1)      # (Hkv, B, nblk, page, ...)
+    hkv, b, nblk, page = g.shape[:4]
+    out = jnp.moveaxis(g.reshape((hkv, b, nblk * page) + g.shape[4:]), 0, 2)
     # mesh-native serving: lanes over the data axes, KV heads over
     # ``model`` — axis 2 is Hkv for K/V pools (B, T, Hkv, Dh) *and* for
     # scale pools (B, T, Hkv), so one hint covers both.  No-op off-mesh.
@@ -361,12 +361,12 @@ def gather_kv_pages(pages: jnp.ndarray,
 @_scoped("attend_paged_decode")
 def attend_paged_decode(
     q: jnp.ndarray,            # (B, 1, Hq, D)
-    k_pages: jnp.ndarray,      # (P, page, Hkv, D) — one layer's pool
+    k_pages: jnp.ndarray,      # (Hkv, P, page, D) — one layer's pool
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     cur_pos: jnp.ndarray,      # (B,) position of the newest token
     window: int = 0,
-    k_scale: Optional[jnp.ndarray] = None,  # (P, page, Hkv) int8 pools only
+    k_scale: Optional[jnp.ndarray] = None,  # (Hkv, P, page) int8 pools only
     v_scale: Optional[jnp.ndarray] = None,
     attn_backend: str = "gather",
     mesh=None,
@@ -417,7 +417,7 @@ def attend_paged_decode(
 @_scoped("attend_paged_prefill")
 def attend_paged_prefill(
     q: jnp.ndarray,            # (B, C, Hq, D) — one prefill chunk
-    k_pages: jnp.ndarray,      # (P, page, Hkv, D) — one layer's pool
+    k_pages: jnp.ndarray,      # (Hkv, P, page, D) — one layer's pool
     v_pages: jnp.ndarray,
     block_tables: jnp.ndarray,  # (B, n_blocks) int32
     positions: jnp.ndarray,    # (B, C) logical positions of the chunk
@@ -456,7 +456,7 @@ def attend_paged_prefill(
     if attn_backend != "gather":
         raise ValueError(f"unknown attention backend {attn_backend!r}")
     b, c = q.shape[:2]
-    t_total = block_tables.shape[1] * k_pages.shape[1]
+    t_total = block_tables.shape[1] * k_pages.shape[2]
     kv_pos = jnp.broadcast_to(
         jnp.arange(t_total, dtype=jnp.int32)[None, :], (b, t_total))
     limit = jnp.minimum(seq_lens, pos0 + c)

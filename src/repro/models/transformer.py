@@ -846,9 +846,21 @@ def _scatter_targets(block_tables, positions, valid, page_size):
     return pidx, poff
 
 
+def _scatter_heads(pool, pidx, poff, val):
+    """Write per-token values into one layer's head-major pool.
+
+    ``pool``: ``(Hkv, P, page[, Dh])``; ``pidx`` / ``poff``: ``(B,)`` or
+    ``(B, C)`` scatter targets; ``val``: ``(B[, C], Hkv[, Dh])`` — the
+    head axis moves to the front to meet the pool's layout.
+    """
+    head_ax = pidx.ndim
+    return pool.at[:, pidx, poff].set(
+        jnp.moveaxis(val, head_ax, 0).astype(pool.dtype))
+
+
 def decode_step_paged(
     params: Params,
-    pages,                               # KVPages: k/v (L, P, page, Hkv, Dh)
+    pages,                               # KVPages: k/v (L, Hkv, P, page, Dh)
     block_tables: jnp.ndarray,           # (B, n_blocks) int32
     pos: jnp.ndarray,                    # (B,) logical token count per lane
     active: jnp.ndarray,                 # (B,) bool — lanes decoding now
@@ -910,20 +922,18 @@ def decode_step_paged(
         if quant:
             kq, ks_new = _quantize_kv(k[:, 0])
             vq, vs_new = _quantize_kv(v[:, 0])
-            nkp = kp.at[pidx, poff].set(kq)
-            nvp = vp.at[pidx, poff].set(vq)
-            nks = xs["ks"].at[pidx, poff].set(
-                ks_new.astype(xs["ks"].dtype))
-            nvs = xs["vs"].at[pidx, poff].set(
-                vs_new.astype(xs["vs"].dtype))
+            nkp = _scatter_heads(kp, pidx, poff, kq)
+            nvp = _scatter_heads(vp, pidx, poff, vq)
+            nks = _scatter_heads(xs["ks"], pidx, poff, ks_new)
+            nvs = _scatter_heads(xs["vs"], pidx, poff, vs_new)
             o = attend_paged_decode(q, nkp, nvp, block_tables, pos, win,
                                     k_scale=nks, v_scale=nvs,
                                     attn_backend=attn_backend,
                                     mesh=mesh, model_axis=model_axis)
             ys["ks"], ys["vs"] = nks, nvs
         else:
-            nkp = kp.at[pidx, poff].set(k[:, 0].astype(kp.dtype))
-            nvp = vp.at[pidx, poff].set(v[:, 0].astype(vp.dtype))
+            nkp = _scatter_heads(kp, pidx, poff, k[:, 0])
+            nvp = _scatter_heads(vp, pidx, poff, v[:, 0])
             o = attend_paged_decode(q, nkp, nvp, block_tables, pos, win,
                                     attn_backend=attn_backend,
                                     mesh=mesh, model_axis=model_axis)
@@ -1009,12 +1019,10 @@ def prefill_chunk(
         if quant:
             kq, ks_new = _quantize_kv(k)
             vq, vs_new = _quantize_kv(v)
-            nkp = kp.at[pidx, poff].set(kq)
-            nvp = vp.at[pidx, poff].set(vq)
-            nks = xs["ks"].at[pidx, poff].set(
-                ks_new.astype(xs["ks"].dtype))
-            nvs = xs["vs"].at[pidx, poff].set(
-                vs_new.astype(xs["vs"].dtype))
+            nkp = _scatter_heads(kp, pidx, poff, kq)
+            nvp = _scatter_heads(vp, pidx, poff, vq)
+            nks = _scatter_heads(xs["ks"], pidx, poff, ks_new)
+            nvs = _scatter_heads(xs["vs"], pidx, poff, vs_new)
             # scales stay folded into the probabilities on both read
             # paths (attend_dense_quant math == the fused grid's in-VMEM
             # folding) — the int8 view is never dequantized wholesale.
@@ -1025,8 +1033,8 @@ def prefill_chunk(
                                      mesh=mesh, model_axis=model_axis)
             ys["ks"], ys["vs"] = nks, nvs
         else:
-            nkp = kp.at[pidx, poff].set(k.astype(kp.dtype))
-            nvp = vp.at[pidx, poff].set(v.astype(vp.dtype))
+            nkp = _scatter_heads(kp, pidx, poff, k)
+            nvp = _scatter_heads(vp, pidx, poff, v)
             o = attend_paged_prefill(q, nkp, nvp, block_tables, positions,
                                      pos0, seq_lens, win,
                                      attn_backend=attn_backend,

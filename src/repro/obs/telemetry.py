@@ -21,6 +21,8 @@ import contextlib
 from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 from repro.obs import clock as _clock
 from repro.obs import spans
 from repro.obs.costs import CostLedger, OpCost
@@ -34,11 +36,6 @@ from repro.obs.trace import (
     SCHED_TID,
     ChromeTracer,
 )
-
-try:  # optional: align host spans with XLA device profiles
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - jax always present in-container
-    _TraceAnnotation = None
 
 _NULLCTX = contextlib.nullcontext()
 
@@ -62,7 +59,7 @@ class Telemetry:
         self.clock = clock or _clock.now
         self.registry = MetricsRegistry()
         self.tracer = ChromeTracer(self.clock) if trace else None
-        self._jax_ann = jax_annotations and _TraceAnnotation is not None
+        self._jax_ann = jax_annotations
         self.timelines: Dict[int, RequestTimeline] = {}
         self._finished: Deque[int] = deque()
         self._max_timelines = max_timelines

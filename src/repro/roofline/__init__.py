@@ -1,7 +1,10 @@
 from repro.roofline.analysis import (
-    HW_V5E,
+    PEAKS,
+    V5E,
     collective_bytes_from_hlo,
+    peaks,
     roofline_report,
 )
 
-__all__ = ["HW_V5E", "collective_bytes_from_hlo", "roofline_report"]
+__all__ = ["PEAKS", "V5E", "collective_bytes_from_hlo", "peaks",
+           "roofline_report"]

@@ -9,8 +9,9 @@ module.  Collective bytes are NOT in cost_analysis: we parse the
 post-optimization HLO text and sum the (per-device) output bytes of every
 all-gather / all-reduce / reduce-scatter / all-to-all / collective-permute.
 
-Hardware constants: TPU v5e — 197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware constants come from :data:`PEAKS`, keyed by the
+``device_kind`` JAX reports; a kind the table lacks is an error, never a
+default.
 """
 
 from __future__ import annotations
@@ -18,11 +19,31 @@ from __future__ import annotations
 import re
 from typing import Any, Dict, Optional
 
-HW_V5E = {
-    "peak_flops": 197e12,   # bf16 FLOP/s per chip
-    "hbm_bw": 819e9,        # bytes/s per chip
-    "link_bw": 50e9,        # bytes/s per ICI link
+V5E = "TPU v5 lite"  # jax.devices()[0].device_kind of a TPU v5e chip
+
+# Published per-chip peaks (Google Cloud documentation, "TPU v5e"):
+# 197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of
+# chip-to-chip interconnect over 4 ICI links (50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    V5E: {
+        "peak_flops": 197e12,       # bf16 FLOP/s per chip
+        "peak_int8_ops": 393e12,    # int8 OP/s per chip
+        "hbm_bw": 819e9,            # bytes/s per chip
+        "hbm_bytes": 16e9,          # bytes per chip
+        "link_bw": 50e9,            # bytes/s per ICI link
+    },
 }
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The peak table of one device kind; raises ``KeyError`` for a kind
+    without published numbers here (a CPU, or an unlisted TPU)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; known: "
+            f"{sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -96,11 +117,12 @@ def roofline_report(
     compiled,
     n_devices: int,
     *,
+    device_kind: str,
     model_flops: Optional[float] = None,
     model_bytes: Optional[float] = None,
-    hw: Dict[str, float] = HW_V5E,
 ) -> Dict[str, Any]:
-    """Build the §Roofline record for one compiled cell.
+    """Build the §Roofline record for one compiled cell, against the peaks
+    of ``device_kind`` (the chip the cell targets — see :func:`peaks`).
 
     Primary numbers come from the trip-count-aware HLO analyzer
     (repro/roofline/hlo_cost.py) — XLA's own cost_analysis visits while
@@ -109,6 +131,7 @@ def roofline_report(
     """
     from repro.roofline.hlo_cost import analyze_hlo_text
 
+    hw = peaks(device_kind)
     cost = compiled.cost_analysis()
     xla_flops = _cost_value(cost, "flops")
     xla_bytes = _cost_value(cost, "bytes accessed")
@@ -142,6 +165,7 @@ def roofline_report(
         mem["error"] = str(e)
 
     report = {
+        "device_kind": device_kind,
         "n_devices": n_devices,
         "flops_per_device": flops_dev,
         "bytes_per_device": bytes_dev,

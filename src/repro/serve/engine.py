@@ -61,6 +61,7 @@ from repro.serve.pages import (
     PageAllocator,
     fork_tail_page,
     init_kv_pages,
+    page_arrays,
     pages_for,
 )
 from repro.serve.prefix_cache import PrefixCache
@@ -222,7 +223,11 @@ class ServeEngine:
         self.plan = resolve_plan(self.scfg.engine, mesh=mesh)
         self.eng = self.plan  # back-compat alias
         if self.plan is not None and self.plan.bits:
-            params = quantize_params(params, cfg, self.plan.bits)
+            # one compiled program: eager packing would hold an f32 copy
+            # of each whole stacked weight at once (GBs at real widths);
+            # already-packed params pass through unchanged
+            params = jax.jit(quantize_params, static_argnums=(1, 2))(
+                params, cfg, self.plan.bits)
         if mesh is not None:
             params = jax.device_put(params, param_shardings(mesh, params))
         self.params = params
@@ -1146,7 +1151,9 @@ class ServeEngine:
     def _retire_paged(self, limit_only: bool = False) -> List[Request]:
         done = []
         for slot, req in enumerate(self.sched.slot_req):
-            if req is None:
+            if req is None or req.prefill_pos < len(req.prefill_tokens):
+                # mid-prefill: even a max_new_tokens=0 request finishes
+                # its prefill first and retires holding its prompt logits
                 continue
             if self._should_retire(req, limit_only):
                 req.done = True
@@ -1196,12 +1203,9 @@ class ServeEngine:
         if not private:
             return
         idx = jnp.asarray(private, jnp.int32)
-        kw = {"k": self.pages.k.at[:, idx].set(0),
-              "v": self.pages.v.at[:, idx].set(0)}
-        if self.pages.quantized:
-            kw["k_scale"] = self.pages.k_scale.at[:, idx].set(0)
-            kw["v_scale"] = self.pages.v_scale.at[:, idx].set(0)
-        self.pages = self.pages.replace(**kw)
+        self.pages = self.pages.replace(**{
+            name: a.at[:, :, idx].set(0)
+            for name, a in page_arrays(self.pages).items()})
 
     def _fault(self, slot: int, req: Request, kind: str) -> None:
         """One lane's step failed (simulated device error or non-finite

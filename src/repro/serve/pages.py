@@ -7,12 +7,14 @@ the fixed-slot engine reserves.
 Two pieces:
 
 * :class:`KVPages` — the device-side page pool, a registered JAX pytree.
-  Storage is ``(L, P, page_size, Hkv, Dh)`` per K and V: every layer sees
-  the same physical page ids, so one ``(B, n_blocks)`` block table per
-  request addresses all layers.  With ``kv_bits=8`` the pools are int8
-  bit-planed (per-(token, head) scales ride along as ``(L, P, page_size,
-  Hkv)`` bf16 pools) — the ``EnginePlan.kv_bits`` knob applied to the
-  cache exactly as ``plan.bits`` is applied to the weights.
+  Storage is head-major, ``(L, Hkv, P, page_size, Dh)`` per K and V:
+  every layer and head sees the same physical page ids, so one
+  ``(B, n_blocks)`` block table per request addresses all of them, and
+  one (head, page) is a contiguous ``(page_size, Dh)`` tile — the block
+  the fused kernel streams.  With ``kv_bits=8`` the pools are int8
+  bit-planed (per-(token, head) scales ride along as ``(L, Hkv, P,
+  page_size)`` bf16 pools) — the ``EnginePlan.kv_bits`` knob applied to
+  the cache exactly as ``plan.bits`` is applied to the weights.
 
 * :class:`PageAllocator` — the host-side free list and block-table
   bookkeeping: capacity-based admission (``can_admit``), on-demand page
@@ -78,10 +80,11 @@ PAGED_FAMILIES = ("dense", "vlm", "audio", "moe")
 class KVPages:
     """Device-side paged KV pool for all layers (a registered JAX pytree).
 
-    ``k`` / ``v``: ``(L, P, page_size, Hkv, Dh)`` in the cache storage dtype
-    (int8 when ``kv_bits=8``).  ``k_scale`` / ``v_scale``: per-(token, head)
-    dequantization scales ``(L, P, page_size, Hkv)``, ``None`` unless the
-    pool is quantized.  ``page_size`` and ``kv_bits`` are static aux data.
+    ``k`` / ``v``: ``(L, Hkv, P, page_size, Dh)`` in the cache storage
+    dtype (int8 when ``kv_bits=8``).  ``k_scale`` / ``v_scale``:
+    per-(token, head) dequantization scales ``(L, Hkv, P, page_size)``,
+    ``None`` unless the pool is quantized.  ``page_size`` and ``kv_bits``
+    are static aux data.
     """
 
     k: jnp.ndarray
@@ -94,7 +97,7 @@ class KVPages:
     # ------------------------------------------------------------- helpers
     @property
     def n_pages(self) -> int:
-        return self.k.shape[1]
+        return self.k.shape[2]
 
     @property
     def quantized(self) -> bool:
@@ -147,12 +150,12 @@ def init_kv_pages(cfg: ModelConfig, n_pages: int, page_size: int,
     if kv_bits:
         dtype = jnp.int8
     dh, hkv, nl = cfg.resolved_head_dim, cfg.n_kv_heads, cfg.n_layers
-    shape = (nl, n_pages, page_size, hkv, dh)
+    shape = (nl, hkv, n_pages, page_size, dh)
     k = jnp.zeros(shape, dtype)
     v = jnp.zeros(shape, dtype)
     ks = vs = None
     if kv_bits:
-        sshape = (nl, n_pages, page_size, hkv)
+        sshape = (nl, hkv, n_pages, page_size)
         ks = jnp.zeros(sshape, jnp.bfloat16)
         vs = jnp.zeros(sshape, jnp.bfloat16)
     return KVPages(k, v, ks, vs, page_size, kv_bits)
@@ -513,11 +516,15 @@ def fork_tail_page(pages: KVPages, src: jnp.ndarray,
     compilation serves every fork; the pool is donated so XLA can update
     the buffers in place.
     """
-    upd = {
-        "k": pages.k.at[:, dst].set(pages.k[:, src]),
-        "v": pages.v.at[:, dst].set(pages.v[:, src]),
-    }
+    return pages.replace(**{
+        name: a.at[:, :, dst].set(a[:, :, src])
+        for name, a in page_arrays(pages).items()})
+
+
+def page_arrays(pages: KVPages) -> dict:
+    """The pool's arrays by field name (scale pools only when quantized);
+    each carries the physical page axis at position 2."""
+    out = {"k": pages.k, "v": pages.v}
     if pages.quantized:
-        upd["k_scale"] = pages.k_scale.at[:, dst].set(pages.k_scale[:, src])
-        upd["v_scale"] = pages.v_scale.at[:, dst].set(pages.v_scale[:, src])
-    return pages.replace(**upd)
+        out["k_scale"], out["v_scale"] = pages.k_scale, pages.v_scale
+    return out

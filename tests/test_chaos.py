@@ -24,12 +24,8 @@ from repro.serve import AuditError, ServeEngine, ServeFrontend
 
 from conftest import reduced_f32
 
-try:  # pragma: no cover - exercised only where hypothesis is installed
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # the image does not ship hypothesis: seeded replay
-    from _hypothesis_shim import given, settings
-    from _hypothesis_shim import strategies as st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 PROMPTS = [[1, 2, 3], [4], [5, 6], [7, 8, 9, 10]]
 
@@ -197,8 +193,8 @@ class TestIsolation:
         assert eng.alloc._mapped[0]
         idx = jnp.asarray(eng.alloc._mapped[0], jnp.int32)
         eng.pages = eng.pages.replace(
-            k=eng.pages.k.at[:, idx].set(jnp.nan),
-            v=eng.pages.v.at[:, idx].set(jnp.nan))
+            k=eng.pages.k.at[:, :, idx].set(jnp.nan),
+            v=eng.pages.v.at[:, :, idx].set(jnp.nan))
         eng._fault(0, victim, "nan_logits")  # budget 0 -> quarantine
         assert victim.finish_reason == "error"
         assert np.isfinite(np.asarray(eng.pages.k)).all()

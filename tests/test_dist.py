@@ -31,6 +31,7 @@ def _run_sub(code: str):
     pre = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys
         sys.path.insert(0, "src")
         sys.path.insert(0, "tests")
@@ -119,12 +120,12 @@ class TestKVPagesSpecs:
         pages = jax.eval_shape(
             lambda: init_kv_pages(cfg, 8, 4, kv_bits=8))
         sh = cache_shardings(self._mesh(), pages)
-        # (L, P, page, Hkv, Dh): pages over data, KV heads over model
-        assert sh.k.spec == P(None, ("data",), None, "model", None)
+        # (L, Hkv, P, page, Dh): KV heads over model, pages over data
+        assert sh.k.spec == P(None, "model", ("data",), None, None)
         assert sh.v.spec == sh.k.spec
-        # (L, P, page, Hkv): the scale pool's trailing head axis must
-        # match its K/V pool (an unsharded scale would desync dequant)
-        assert sh.k_scale.spec == P(None, ("data",), None, "model")
+        # (L, Hkv, P, page): the scale pool's head axis must match its
+        # K/V pool (an unsharded scale would desync dequant)
+        assert sh.k_scale.spec == P(None, "model", ("data",), None)
         assert sh.v_scale.spec == sh.k_scale.spec
 
     def test_page_state_specs(self):
@@ -196,17 +197,19 @@ class TestMultiDevice:
         _run_sub("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
+        from repro.dist import make_mesh
         from repro.dist.collectives import compressed_psum_leaf
-        from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
-        @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                 out_specs=P("pod"))
         def plain(x):
             return jax.lax.psum(x, "pod")
 
-        @partial(shard_map, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
+                 out_specs=P("pod"))
         def comp(x):
             return compressed_psum_leaf(x, "pod", bits=8)
 
@@ -226,18 +229,18 @@ class TestMultiDevice:
         _run_sub("""
         from functools import partial
         from jax.sharding import PartitionSpec as P
+        from repro.dist import make_mesh
         from repro.dist.collectives import compressed_psum_leaf
-        from jax.experimental.shard_map import shard_map
 
-        mesh = jax.make_mesh((8,), ("pod",))
+        mesh = make_mesh((8,), ("pod",))
 
         def pair(x, bits):
-            @partial(shard_map, mesh=mesh, in_specs=P("pod"),
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
                      out_specs=P("pod"))
             def plain(v):
                 return jax.lax.psum(v, "pod")
 
-            @partial(shard_map, mesh=mesh, in_specs=P("pod"),
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("pod"),
                      out_specs=P("pod"))
             def comp(v):
                 return compressed_psum_leaf(v, "pod", bits=bits)

@@ -2,10 +2,7 @@
 
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.controller import CycleModel, GemvTileController, run_gemv
 from repro.core.isa import (

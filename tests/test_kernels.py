@@ -4,10 +4,7 @@ pure-jnp oracles (interpret mode), plus hypothesis property checks."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.gemv_engine import (
     gemv_bit_serial_reference,

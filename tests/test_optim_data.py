@@ -4,10 +4,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.config.base import ModelConfig, TrainConfig
 from repro.data import DataPipeline

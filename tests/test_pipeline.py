@@ -33,6 +33,7 @@ def test_pipeline_matches_sequential_multi_device():
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys; sys.path.insert(0, "src")
         import jax, jax.numpy as jnp
         from repro.dist import make_mesh, use_mesh

@@ -19,10 +19,7 @@ import subprocess
 import sys
 import textwrap
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp
@@ -181,6 +178,7 @@ def test_prefix_cache_on_mesh_token_identical():
     pre = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys
         sys.path.insert(0, "src"); sys.path.insert(0, "tests")
         import jax
@@ -262,20 +260,20 @@ def test_fork_tail_page_copies_all_layers_and_scales():
     for kv_bits in (0, 8):
         pages = init_kv_pages(cfg, 5, 4, kv_bits=kv_bits)
         key = jax.random.PRNGKey(1)
-        fill = jax.random.normal(key, pages.k[:, 2].shape)
-        pages = pages.replace(k=pages.k.at[:, 2].set(
+        fill = jax.random.normal(key, pages.k[:, :, 2].shape)
+        pages = pages.replace(k=pages.k.at[:, :, 2].set(
             fill.astype(pages.k.dtype)))
         if kv_bits:
-            pages = pages.replace(k_scale=pages.k_scale.at[:, 2].set(0.5))
+            pages = pages.replace(k_scale=pages.k_scale.at[:, :, 2].set(0.5))
         forked = fork_tail_page(pages, jnp.int32(2), jnp.int32(4))
-        np.testing.assert_array_equal(np.asarray(forked.k[:, 4]),
-                                      np.asarray(forked.k[:, 2]))
-        np.testing.assert_array_equal(np.asarray(forked.v[:, 4]),
-                                      np.asarray(forked.v[:, 2]))
+        np.testing.assert_array_equal(np.asarray(forked.k[:, :, 4]),
+                                      np.asarray(forked.k[:, :, 2]))
+        np.testing.assert_array_equal(np.asarray(forked.v[:, :, 4]),
+                                      np.asarray(forked.v[:, :, 2]))
         if kv_bits:
             np.testing.assert_array_equal(
-                np.asarray(forked.k_scale[:, 4]),
-                np.asarray(forked.k_scale[:, 2]))
+                np.asarray(forked.k_scale[:, :, 4]),
+                np.asarray(forked.k_scale[:, :, 2]))
 
 
 def test_prefix_cache_requires_paged_mode():

@@ -9,6 +9,8 @@ from repro.roofline.analysis import (
     collective_bytes_from_hlo,
     model_bytes_for_cell,
     model_flops_for_cell,
+    V5E,
+    peaks,
     roofline_report,
 )
 from repro.roofline.hlo_cost import analyze_hlo_text
@@ -116,13 +118,27 @@ class TestRooflineReport:
     def test_report_fields(self):
         c = _compile(lambda a, b: a @ b,
                      jnp.ones((256, 256)), jnp.ones((256, 256)))
-        rep = roofline_report(c, 1, model_flops=2 * 256**3,
+        rep = roofline_report(c, 1, device_kind=V5E,
+                              model_flops=2 * 256**3,
                               model_bytes=3 * 256 * 256 * 4)
         for key in ("compute_s", "memory_s", "collective_s", "dominant",
                     "roofline_fraction", "useful_flops_ratio"):
             assert key in rep
         assert rep["dominant"] in ("compute_s", "memory_s", "collective_s")
         assert 0 < rep["roofline_fraction"] <= 1.5
+
+    def test_peaks_keyed_by_device_kind(self):
+        """The v5e peaks carry their published values; a device kind the
+        table lacks (the CPU this runs on, say) is an error, not a silent
+        v5e default."""
+        assert peaks(V5E)["peak_flops"] == 197e12
+        assert peaks(V5E)["hbm_bw"] == 819e9
+        c = _compile(lambda a: a * 2, jnp.ones((8, 8)))
+        for kind in ("cpu", "TPU v4"):
+            with pytest.raises(KeyError, match="no published peaks"):
+                peaks(kind)
+            with pytest.raises(KeyError):
+                roofline_report(c, 1, device_kind=kind)
 
     def test_model_flops_conventions(self):
         cfg = get_arch("mistral-large-123b")

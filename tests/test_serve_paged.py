@@ -224,3 +224,26 @@ def test_capacity_admission_queues(rng):
     assert all(r.done and len(r.output) == 6 for r in done)
     assert eng.alloc.used_pages == 0  # everything reclaimed
     assert eng.alloc.free_pages == 8
+
+
+def test_zero_token_request_finishes_its_prefill(rng):
+    """A ``max_new_tokens=0`` request whose prompt spans several prefill
+    chunks stays resident until its last chunk lands, then retires with
+    an empty output holding the prompt's last-position logits — the same
+    logits a one-shot forward gives."""
+    from repro.models import forward
+
+    cfg = reduced_f32("qwen2.5-3b")
+    params = init_params(cfg, rng)
+    prompt = list(range(1, 12))            # 11 tokens = 4 chunks of 3
+    scfg = ServeConfig(max_new_tokens=0)
+    eng = ServeEngine(cfg, params, scfg, n_slots=2, max_len=32,
+                      mode="paged", page_size=4, prefill_chunk=3)
+    req = eng.submit(prompt)
+    other = eng.submit([4, 5], max_new_tokens=3)
+    eng.run()
+    assert req.done and req.output == [] and req.finish_reason == "length"
+    assert other.done and len(other.output) == 3
+    want, _ = forward(params, {"tokens": jnp.asarray([prompt])}, cfg)
+    np.testing.assert_allclose(req.last_logits, np.asarray(want)[0, -1],
+                               rtol=1e-4, atol=1e-4)

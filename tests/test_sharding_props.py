@@ -5,10 +5,7 @@ mesh shapes.  The invariant under test: non-divisible dimensions must
 *never* error, only degrade to replication, and any axis that is placed
 must exactly divide its dimension."""
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container image has no hypothesis
-    from _hypothesis_shim import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 import jax
 import jax.numpy as jnp

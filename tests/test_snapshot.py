@@ -192,6 +192,7 @@ def test_recovery_drill_on_mesh():
     pre = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import sys
         sys.path.insert(0, "src"); sys.path.insert(0, "tests")
         import jax
