@@ -1,0 +1,7 @@
+"""Engine: window seconds per ServeEngine.step() call, in ms (offline cell)."""
+
+from bench.yardstick import readers
+
+
+def read(run):
+    return readers.engine_step_ms(run)
