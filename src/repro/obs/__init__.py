@@ -22,9 +22,9 @@ Typical use::
     obs.disable()
 
 ``enable(trace=False)`` keeps metrics/timelines but skips trace-event
-collection; ``enable(jax_annotations=True)`` additionally wraps the
-prefill/decode dispatches in ``jax.profiler.TraceAnnotation`` scopes so
-host spans line up with an XLA device profile.  See
+collection; ``enable(jax_annotations=True)`` additionally writes the
+engine step's ``serve.*`` spans as ``jax.profiler.TraceAnnotation``
+scopes, so they sit on an XLA device profile's clock.  See
 ``docs/observability.md``.
 """
 

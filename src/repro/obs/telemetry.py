@@ -31,7 +31,6 @@ from repro.obs.spans import RequestTimeline
 from repro.obs.trace import (
     CACHE_TID,
     ENGINE_TID,
-    MEM_TID,
     PAGES_TID,
     SCHED_TID,
     ChromeTracer,
@@ -47,9 +46,10 @@ class Telemetry:
     drive every timestamp manually.  ``trace=False`` keeps the metrics
     and timelines but skips Chrome-event collection (the overhead-bench
     "metrics-on" configuration); ``jax_annotations=True`` additionally
-    wraps prefill/decode dispatch in ``jax.profiler.TraceAnnotation``
-    scopes.  Finished timelines are kept in a bounded deque
-    (``max_timelines``) so week-long runs do not grow host memory.
+    writes the engine's ``serve.*`` spans as
+    ``jax.profiler.TraceAnnotation`` scopes, on the device trace's clock.
+    Finished timelines are kept in a bounded deque (``max_timelines``)
+    so week-long runs do not grow host memory.
     """
 
     enabled = True
@@ -66,7 +66,6 @@ class Telemetry:
         self._step_n = 0
         self._n_slots = 0
         self.costs = CostLedger()
-        self._last_pages = (0, 0, 0)  # (free, cached, evictable)
         # per-op (flops, bytes) counter pairs, resolved once: on_costs
         # runs on every prefill/decode dispatch and labeled registry
         # lookups are the hot part of the charge
@@ -103,7 +102,6 @@ class Telemetry:
         tr.thread_name(SCHED_TID, "scheduler")
         tr.thread_name(CACHE_TID, "prefix-cache")
         tr.thread_name(PAGES_TID, "pages")
-        tr.thread_name(MEM_TID, "memory")
 
     def _timeline(self, rid: int) -> Optional[RequestTimeline]:
         return self.timelines.get(rid)
@@ -125,32 +123,24 @@ class Telemetry:
         self._c_steps.inc()
         self._h_step.observe(t1 - t0)
         if self.tracer is not None:
-            # sample pool occupancy into the "memory" track once per step
-            free, cached, evictable = self._last_pages
-            self.tracer.counter(MEM_TID, "memory",
-                                {"free": free, "cached": cached,
-                                 "evictable": evictable})
             self.tracer.end(ENGINE_TID, "step", t=t1)
 
-    def phase(self, name: str):
-        """Span a step phase (admit/prefill/decode) on the engine track."""
+    def annotate(self, name: str):
+        """A named span of the engine step: a ``B``/``E`` pair on the
+        engine track when tracing, and a ``jax.profiler.TraceAnnotation``
+        scope when ``jax_annotations`` is set."""
         if self.tracer is None:
-            return _NULLCTX
-        return self._phase_ctx(name)
+            return _TraceAnnotation(name) if self._jax_ann else _NULLCTX
+        return self._span(name)
 
     @contextlib.contextmanager
-    def _phase_ctx(self, name: str):
+    def _span(self, name: str):
         self.tracer.begin(ENGINE_TID, name)
         try:
-            yield
+            with _TraceAnnotation(name) if self._jax_ann else _NULLCTX:
+                yield
         finally:
             self.tracer.end(ENGINE_TID, name)
-
-    def annotate(self, name: str):
-        """``jax.profiler.TraceAnnotation`` scope (no-op unless enabled)."""
-        if self._jax_ann:
-            return _TraceAnnotation(name)
-        return _NULLCTX
 
     # ----------------------------------------------------- request lifecycle
     def on_submit(self, rid: int, prompt_len: int, t: float) -> None:
@@ -359,7 +349,6 @@ class Telemetry:
         g_free.set(free)
         g_cached.set(cached)
         g_evictable.set(evictable)
-        self._last_pages = (free, cached, evictable)
         if self.tracer is not None:
             self.tracer.counter(PAGES_TID, "pages",
                                 {"free": free, "cached": cached,
@@ -463,9 +452,6 @@ class NullTelemetry:
 
     def step_end(self, t0):
         pass
-
-    def phase(self, name):
-        return _NULLCTX
 
     def annotate(self, name):
         return _NULLCTX

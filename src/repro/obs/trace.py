@@ -6,7 +6,8 @@ format) — load the file in Perfetto (https://ui.perfetto.dev) or
 ``chrome://tracing`` and the serve path renders as a timeline:
 
 * **engine track** (tid 0): one ``B``/``E`` pair per ``ServeEngine.step``
-  with nested ``admit`` / ``prefill`` / ``decode`` phase spans;
+  with the step's nested ``serve.*`` spans (``serve.schedule``,
+  ``serve.prefill_chunk``, ``serve.decode_step``, ...);
 * **one track per lane** (tid 1..n_slots): ``X`` (complete) spans for
   each chunked-prefill and decode-step dispatch the lane took part in,
   tagged with the owning request id;
@@ -18,9 +19,9 @@ format) — load the file in Perfetto (https://ui.perfetto.dev) or
 
 Timestamps are microseconds on the telemetry clock, relative to tracer
 construction, so host spans line up with each other exactly; with
-``jax_annotations`` enabled the same dispatch sites also carry
-``jax.profiler.TraceAnnotation`` scopes so the host timeline can be
-aligned with an XLA device profile captured by ``jax.profiler.trace``.
+``jax_annotations`` enabled the same ``serve.*`` spans are also written
+as ``jax.profiler.TraceAnnotation`` scopes, on the clock of an XLA
+device profile captured by ``jax.profiler.trace``.
 
 The event buffer is bounded (``max_events``): a runaway run drops
 events past the cap (counted in ``dropped``) instead of eating the
@@ -38,7 +39,6 @@ ENGINE_TID = 0
 SCHED_TID = 1000
 CACHE_TID = 1001
 PAGES_TID = 1002
-MEM_TID = 1003  # "memory" track: pool occupancy/evictable/cached per step
 
 REQUIRED_KEYS = ("ph", "ts", "pid", "tid", "name")
 

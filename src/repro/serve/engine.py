@@ -29,7 +29,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
-from typing import Deque, Dict, List, Optional
+from typing import Deque, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -1000,23 +1000,26 @@ class ServeEngine:
         self._engine_step += 1
         finished: List[Request] = []
         self._errored_step = []  # quarantines land here (terminal too)
-        if self.chaos is not None and self.chaos.fire("preempt_storm"):
-            # mass eviction drill: recompute-style, token-preserving
-            # (fire() itself reports the fault through chaos.obs)
-            self.sched.preempt_storm()
-        with self.obs.phase("admit"):
+        # host spans tile the step (``serve.*``, through obs.annotate):
+        # none encloses the dispatch spans, so a device-idle gap is put
+        # down to the innermost thing the host was doing
+        with self.obs.annotate("serve.schedule"):
+            if self.chaos is not None and self.chaos.fire("preempt_storm"):
+                # mass eviction drill: recompute-style, token-preserving
+                # (fire() itself reports the fault through chaos.obs)
+                self.sched.preempt_storm()
             self.sched.admit()
             self._apply_forks()
         self._maybe_audit(2)
-        with self.obs.phase("prefill"):
-            self._prefill_once()
+        self._prefill_once()
         self._maybe_audit(2)
         # pre-decode retire: max_new_tokens=0 must emit no tokens
-        finished.extend(self._retire_paged(limit_only=True))
-        with self.obs.phase("decode"):
-            self._decode_once_paged()
+        with self.obs.annotate("serve.retire"):
+            finished.extend(self._retire_paged(limit_only=True))
+        self._decode_once_paged()
         self._maybe_audit(2)
-        finished.extend(self._retire_paged())
+        with self.obs.annotate("serve.retire"):
+            finished.extend(self._retire_paged())
         self._maybe_audit(1)
         finished.extend(self._errored_step)
         return finished
@@ -1049,17 +1052,25 @@ class ServeEngine:
         """Advance every pending prompt by one batched chunk."""
         codebooks = (self.cfg.n_codebooks
                      if self.cfg.family == "audio" else 0)
-        batch = self.sched.prefill_batch(audio_codebooks=codebooks)
+        with self.obs.annotate("serve.schedule"):
+            batch = self.sched.prefill_batch(audio_codebooks=codebooks)
         if batch is None:
             return
         tokens, pos0, seq_lens, lanes = batch
         t0 = self.obs.now()
-        bt, _ = self.alloc.device_tables(self._table_shardings)
+        with self.obs.annotate("serve.tables"):
+            bt, _ = self.alloc.device_tables(self._table_shardings)
         with self.obs.annotate("serve.prefill_chunk"):
             logits, self.pages = self._prefill_paged(
                 self.params, self.pages, bt, jnp.asarray(tokens),
                 jnp.asarray(pos0), jnp.asarray(seq_lens))
-            lg = np.asarray(logits)  # host sync: the chunk has landed
+            with self.obs.annotate("serve.logits_to_host"):
+                lg = np.asarray(logits)  # host sync: the chunk has landed
+        with self.obs.annotate("serve.lane_update"):
+            self._prefill_lanes(lanes, lg, t0)
+
+    def _prefill_lanes(self, lanes, lg: np.ndarray, t0: float) -> None:
+        """Per-lane bookkeeping after a prefill chunk landed."""
         self.obs.on_prefill(
             [(slot, self.sched.slot_req[slot].rid, n)
              for slot, n in lanes], t0)
@@ -1093,11 +1104,42 @@ class ServeEngine:
                                              self.alloc.block_row(slot))
 
     def _decode_once_paged(self) -> None:
-        lanes = self.sched.decode_lanes()
+        with self.obs.annotate("serve.schedule"):
+            ready = self._grant_decode()
+        if not ready:
+            return
+        with self.obs.annotate("serve.sample"):
+            updates: Dict[int, int] = {}
+            tnow = self._clock()
+            for slot, req in ready:
+                tok = self._sample_next(req)
+                if not req.output and req.ttft is None:
+                    req.ttft = tnow - req.submit_t
+                    self.obs.on_first_token(req.rid, req.ttft, tnow)
+                else:
+                    self.obs.on_token(req.rid, tnow)
+                req.output.append(tok)
+                updates[slot] = tok
+            tokens = self._lane_tokens(updates)
+            active = jnp.asarray(self.sched.lane_mask(updates))
+        t0 = self.obs.now()
+        with self.obs.annotate("serve.tables"):
+            bt, pos = self.alloc.device_tables(self._table_shardings)
+        with self.obs.annotate("serve.decode_step"):
+            logits, self.pages = self._decode_paged(
+                self.params, self.pages, bt, pos, active, tokens)
+            with self.obs.annotate("serve.logits_to_host"):
+                lg = np.asarray(logits)  # host sync: the step has landed
+        with self.obs.annotate("serve.lane_update"):
+            self._decode_lanes(ready, lg, t0)
+
+    def _grant_decode(self) -> List[Tuple[int, Request]]:
+        """The decoding lanes granted a page for this step, charged to
+        the scheduler's budget."""
         # page grant first (may preempt): a preempted lane drops out of
         # this step and resumes via re-prefill with identical greedy state
         ready = []
-        for slot, req in lanes:
+        for slot, req in self.sched.decode_lanes():
             if len(req.output) >= req.max_new_tokens:
                 continue
             if self.sched.slot_req[slot] is not req:
@@ -1107,28 +1149,13 @@ class ServeEngine:
         # a later grant may have preempted an earlier-granted lane: keep
         # only lanes still resident
         ready = [(s, r) for s, r in ready if self.sched.slot_req[s] is r]
-        if not ready:
-            return
-        self.sched.charge_decode(ready)
-        updates: Dict[int, int] = {}
-        tnow = self._clock()
-        for slot, req in ready:
-            tok = self._sample_next(req)
-            if not req.output and req.ttft is None:
-                req.ttft = tnow - req.submit_t
-                self.obs.on_first_token(req.rid, req.ttft, tnow)
-            else:
-                self.obs.on_token(req.rid, tnow)
-            req.output.append(tok)
-            updates[slot] = tok
-        tokens = self._lane_tokens(updates)
-        active = jnp.asarray(self.sched.lane_mask(updates))
-        t0 = self.obs.now()
-        bt, pos = self.alloc.device_tables(self._table_shardings)
-        with self.obs.annotate("serve.decode_step"):
-            logits, self.pages = self._decode_paged(
-                self.params, self.pages, bt, pos, active, tokens)
-            lg = np.asarray(logits)  # host sync: the step has landed
+        if ready:
+            self.sched.charge_decode(ready)
+        return ready
+
+    def _decode_lanes(self, ready: List[Tuple[int, Request]],
+                      lg: np.ndarray, t0: float) -> None:
+        """Per-lane bookkeeping after a decode step landed."""
         self.obs.on_decode([(s, r.rid) for s, r in ready], t0)
         self._charge_decode([r.rid for _, r in ready])
         fault_slot, lg = self._inject_lane_chaos(

@@ -218,8 +218,7 @@ class PageAllocator:
 
     def _emit_pages(self) -> None:
         """Publish pool occupancy (free / cache-resident / evictable) to
-        telemetry — the ``C`` counter series on the pages trace track,
-        and the per-step "memory" track sample."""
+        telemetry — the ``C`` counter series on the pages trace track."""
         self.obs.on_pages(
             len(self.free),
             self._cache.cached_pages if self._cache is not None else 0,

@@ -12,6 +12,7 @@ where mid-run submissions inherited the engine's run start as their
 zero point.
 """
 
+import contextlib
 import json
 
 import pytest
@@ -29,6 +30,7 @@ from repro.obs import (
     validate_trace,
 )
 from repro.obs import spans
+from repro.obs.telemetry import NullTelemetry
 from repro.obs.trace import CACHE_TID, ENGINE_TID, SCHED_TID
 from repro.serve import AdmissionRejected, ServeEngine
 
@@ -343,3 +345,105 @@ def test_ttft_is_per_request_not_run_relative(rng):
     # the timelines agree with the Request fields
     assert tel.timelines[r2.rid].ttft == pytest.approx(r2.ttft)
     assert tel.registry.histogram("serve_ttft_s").count == 2
+
+
+# ------------------------------------------------- spans of the paged step
+DISPATCH = ("serve.prefill_chunk", "serve.decode_step")
+STEP_SPANS = {"serve.schedule", "serve.tables", "serve.prefill_chunk",
+              "serve.decode_step", "serve.logits_to_host", "serve.sample",
+              "serve.lane_update", "serve.retire"}
+
+
+class RecordingTelemetry(NullTelemetry):
+    """The disabled telemetry with only ``annotate`` overridden, as the
+    benchmark's traced runs switch the spans on: records each span with
+    the spans open around it."""
+
+    def __init__(self):
+        self.opened = []  # (name, names of the spans enclosing it)
+        self._stack = []
+
+    def annotate(self, name):
+        @contextlib.contextmanager
+        def span():
+            self.opened.append((name, tuple(self._stack)))
+            self._stack.append(name)
+            try:
+                yield
+            finally:
+                self._stack.pop()
+        return span()
+
+
+def _serve_counting(eng, prompts):
+    """Serve ``prompts`` step by step; returns the span names each step
+    opened and the number of prefill and decode dispatches."""
+    calls = {"prefill": 0, "decode": 0}
+
+    def counted(kind, fn):
+        def call(*a, **kw):
+            calls[kind] += 1
+            return fn(*a, **kw)
+        return call
+
+    eng._prefill_paged = counted("prefill", eng._prefill_paged)
+    eng._decode_paged = counted("decode", eng._decode_paged)
+    for p in prompts:
+        eng.submit(list(p))
+    per_step = []
+    while eng.has_work():
+        n0 = len(eng.obs.opened)
+        eng.step()
+        per_step.append({n for n, _ in eng.obs.opened[n0:]})
+    return per_step, calls
+
+
+def test_paged_step_is_tiled_by_serve_spans(rng):
+    """Every paged step opens its schedule and retire spans, and each
+    dispatch its table upload, logits copy and lane update; only the
+    logits copy nests, inside a dispatch span, so no span encloses the
+    dispatch spans; one ``serve.decode_step`` per decode dispatch."""
+    cfg = reduced_f32("qwen2.5-3b")
+    params = init_params(cfg, rng)
+    rec = RecordingTelemetry()
+    eng = _engine(cfg, params, telemetry=rec, max_new=3)
+    per_step, calls = _serve_counting(
+        eng, [[1, 2, 3, 4, 5, 6, 7], [4], [5, 6, 7, 8]])
+    for names in per_step:
+        assert {"serve.schedule", "serve.retire"} <= names
+        for dispatch in DISPATCH:
+            if dispatch in names:
+                assert {"serve.tables", "serve.logits_to_host",
+                        "serve.lane_update"} <= names
+        if "serve.decode_step" in names:
+            assert "serve.sample" in names
+    assert {n for n, _ in rec.opened} == STEP_SPANS
+    for name, outer in rec.opened:
+        if name == "serve.logits_to_host":
+            assert len(outer) == 1 and outer[0] in DISPATCH
+        else:
+            assert outer == (), (name, outer)
+    count = {n: sum(1 for m, _ in rec.opened if m == n) for n in DISPATCH}
+    assert calls["decode"] > 0 and calls["prefill"] > 0
+    assert count["serve.decode_step"] == calls["decode"]
+    assert count["serve.prefill_chunk"] == calls["prefill"]
+
+
+@pytest.mark.parametrize("jax_annotations", [False, True])
+def test_live_telemetry_puts_step_spans_on_engine_track(rng,
+                                                        jax_annotations):
+    """A live ``Telemetry(trace=True)`` writes the same span names as
+    B/E pairs on the engine track, nested in each step, and the trace
+    validates, with or without the profiler annotations."""
+    cfg = reduced_f32("qwen2.5-3b")
+    params = init_params(cfg, rng)
+    tel = Telemetry(trace=True, jax_annotations=jax_annotations)
+    eng = _engine(cfg, params, telemetry=tel, max_new=3)
+    for p in ([1, 2, 3, 4, 5, 6, 7], [4], [5, 6, 7, 8]):
+        eng.submit(p)
+    eng.run()
+    validate_trace(tel.tracer.export())
+    engine = [e for e in tel.tracer.events
+              if e["tid"] == ENGINE_TID and e["ph"] in ("B", "E")]
+    assert {e["name"] for e in engine} == STEP_SPANS | {"step"}
+    assert not any(e["name"] == "memory" for e in tel.tracer.events)
