@@ -11,10 +11,13 @@ checks what comes out:
 
 * every request finishes with in-vocabulary tokens and finite logits;
 * logits agree with ``models.transformer.forward`` on the same tokens, on
-  the same chip(s): the engine's last decode-step logits against the full
-  forward over prompt + output, and its prefill logits (a second wave of
-  the same prompts, ``max_new_tokens=0``, served from the prefix cache)
-  against the forward over the prompt;
+  the same chip(s): the logits the engine drew each request's last token
+  from against the forward over the prompt and every token before it,
+  and its prefill logits (a second wave of the same prompts,
+  ``max_new_tokens=0``, served from the prefix cache) against the
+  forward over the prompt.  The engine samples inside its jitted step;
+  to keep the logits it is given its own host sampler as an override,
+  which makes it copy them;
 * the compiled decode and prefill steps contain the Pallas kernels
   (``tpu_custom_call``): the fused paged attention, and in the int8 phase
   the bit-plane GEMV.
@@ -86,9 +89,13 @@ def _prompts(rng, vocab, lengths, shared):
 def _serve(eng, prompts, max_new, vocab, check, label):
     """Submit ``prompts`` and drive the engine until it drains; returns
     ``[(tokens, logits)]``: the sequence the final logits condition on
-    (prompt + output) and those logits as float32."""
+    (the prompt and every token before the last) and those logits as
+    float32."""
+    import functools
+
     import numpy as np
 
+    eng._sample_next = functools.partial(type(eng)._sample_next, eng)
     reqs = [eng.submit(p, max_new) for p in prompts]
     eng.run()
     check(all(r.done and r.finish_reason == "length" for r in reqs),
@@ -100,7 +107,7 @@ def _serve(eng, prompts, max_new, vocab, check, label):
     out = []
     for r in reqs:
         lg = np.asarray(r.last_logits, np.float32)
-        out.append((list(r.prompt) + list(r.output), lg))
+        out.append((list(r.prompt) + list(r.output[:-1]), lg))
     check(all(lg.shape == (vocab,) and np.isfinite(lg).all()
               for _, lg in out), f"{label}: logits finite, shape ({vocab},)")
     return out
@@ -157,11 +164,12 @@ def _kernel_calls(eng):
             "decode": eng._decode_paged.lower(
                 eng.params, eng.pages, bt, pos,
                 jnp.asarray(np.zeros((n,), bool)),
-                jnp.asarray(np.zeros((n, 1), np.int32))).compile(),
+                jnp.asarray(np.zeros((n, 1), np.int32)),
+                eng.key).compile(),
             "prefill": eng._prefill_paged.lower(
                 eng.params, eng.pages, bt,
                 jnp.asarray(np.zeros((n, eng.prefill_chunk), np.int32)),
-                pos, pos).compile(),
+                pos, pos, eng.key).compile(),
         }
     out = {}
     for step, compiled in steps.items():

@@ -78,6 +78,9 @@ class Telemetry:
         self._c_tokens = reg.counter("serve_tokens_generated_total")
         self._c_steps = reg.counter("serve_steps_total")
         self._c_prefill_tokens = reg.counter("serve_prefill_tokens_total")
+        self._c_sampled = {
+            where: reg.counter("serve_tokens_sampled_total", where=where)
+            for where in ("device", "host")}
         self._h_step = reg.histogram("serve_step_s")
         self._h_tpot = reg.histogram("serve_tpot_s")
         self._h_ttft = reg.histogram("serve_ttft_s")
@@ -218,6 +221,12 @@ class Telemetry:
         if tl is not None:
             tl.transition(spans.DECODING, t)
             tl.token(t)
+
+    def on_sampled(self, where: str, n: int) -> None:
+        """``n`` next tokens picked after a dispatch landed: ``"device"``
+        inside the jitted step, ``"host"`` through a ``_sample_next`` on
+        a host copy of the logits (an override, or slots mode)."""
+        self._c_sampled[where].inc(n)
 
     def on_token(self, rid: int, t: float) -> None:
         self._c_tokens.inc()
@@ -475,6 +484,9 @@ class NullTelemetry:
         pass
 
     def on_first_token(self, rid, ttft_s, t):
+        pass
+
+    def on_sampled(self, where, n):
         pass
 
     def on_token(self, rid, t):

@@ -7,7 +7,8 @@ through per-request block tables; a scheduler
 (:mod:`repro.serve.scheduler`) admits requests by page capacity, prefills
 prompts in batched chunks through ``prefill_chunk`` (one forward per chunk
 across all pending lanes), and preempts the longest-running request when
-pages run out.  Decode throughput then scales with pool capacity — the
+pages run out.  Each jitted dispatch also samples every lane's next id
+(:mod:`repro.serve.sampler`), so the host fetches ``(B,)`` ids, not logits.  Decode throughput then scales with pool capacity — the
 serving analogue of the paper's GEMV-per-memory-capacity argument.
 
 **Fixed-slot mode** (``mode="slots"``; also the fallback for ssm/hybrid
@@ -29,7 +30,7 @@ import contextlib
 import dataclasses
 import functools
 import logging
-from typing import Deque, Dict, List, Optional, Tuple
+from typing import Deque, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -65,7 +66,7 @@ from repro.serve.pages import (
     pages_for,
 )
 from repro.serve.prefix_cache import PrefixCache
-from repro.serve.sampler import sample
+from repro.serve.sampler import sample, sample_rows
 from repro.serve.scheduler import (
     PRIORITY_WEIGHTS,
     BudgetScheduler,
@@ -101,10 +102,15 @@ class Request:
     max_new_tokens: int
     output: List[int] = dataclasses.field(default_factory=list)
     done: bool = False
-    # logits of the most recent token, fed to the next sampling step.  A
-    # real field now (it used to be injected by ``_prefill_slot``).
+    # host copy of the lane's newest logits: set only where the host
+    # samples (a ``_sample_next`` override, slots mode); the paged engine
+    # samples inside its jitted step and never copies them
     last_logits: Optional[np.ndarray] = dataclasses.field(
         default=None, repr=False)
+    # paged: the newest output id, whose K/V the next decode step writes
+    # (None until a dispatch has sampled one — "ready to decode" — and
+    # again after a preemption or fault sends the request back to prefill)
+    pending: Optional[int] = None
     # --- paged-scheduler state --------------------------------------
     prefill_tokens: List[int] = dataclasses.field(
         default_factory=list, repr=False)
@@ -136,6 +142,17 @@ class Request:
     @_last_logits.setter
     def _last_logits(self, value):
         self.last_logits = value
+
+
+class Sampled(NamedTuple):
+    """What a jitted paged dispatch returns beside the page pool: each
+    lane's next id and whether its logits were all finite (the hot
+    path's only host fetch), the logits themselves (copied only for a
+    ``_sample_next`` override) and the advanced sampling key."""
+    ids: jax.Array       # (B,) int32
+    finite: jax.Array    # (B,) bool
+    logits: jax.Array    # (B, 1, V) or (B, 1, K, V)
+    key: jax.Array
 
 
 class ServeEngine:
@@ -377,20 +394,32 @@ class ServeEngine:
             # instead of copying the whole pool per token/chunk.  The mesh
             # rides in explicitly (the plan may be None on a meshed
             # engine) so the fused kernel can shard_map over it.
+            # Each dispatch also samples every lane's next id from the
+            # logits it just computed (``temperature`` / ``top_k`` are
+            # static: the trace adapts to them), so the host fetches
+            # ``(B,)`` ids and finite flags instead of ``(B, V)`` logits.
             abk_ = self.attn_backend
             mesh_ = mesh
+            temp_, top_k_ = self.scfg.temperature, self.scfg.top_k
+
+            def _sampled(logits, key):
+                key, sub = jax.random.split(key)
+                ids, finite = sample_rows(logits, sub, temp_, top_k_)
+                return Sampled(ids, finite, logits, key)
 
             @functools.partial(jax.jit, donate_argnums=(1,))
-            def _dec(params, pages, bt, pos, active, tokens):
-                return decode_step_paged(params, pages, bt, pos, active,
-                                         tokens, cfg_, plan_,
-                                         attn_backend=abk_, mesh=mesh_)
+            def _dec(params, pages, bt, pos, active, tokens, key):
+                logits, pages = decode_step_paged(
+                    params, pages, bt, pos, active, tokens, cfg_, plan_,
+                    attn_backend=abk_, mesh=mesh_)
+                return _sampled(logits, key), pages
 
             @functools.partial(jax.jit, donate_argnums=(1,))
-            def _pf(params, pages, bt, tokens, pos0, seq_lens):
-                return _prefill_chunk_fn(params, pages, bt, tokens, pos0,
-                                         seq_lens, cfg_, plan_,
-                                         attn_backend=abk_, mesh=mesh_)
+            def _pf(params, pages, bt, tokens, pos0, seq_lens, key):
+                logits, pages = _prefill_chunk_fn(
+                    params, pages, bt, tokens, pos0, seq_lens, cfg_, plan_,
+                    attn_backend=abk_, mesh=mesh_)
+                return _sampled(logits, key), pages
 
             self._decode_paged = _dec
             self._prefill_paged = _pf
@@ -574,7 +603,9 @@ class ServeEngine:
         slots = self.sched.slot_req if self.mode == "paged" else self.slot_req
         for r in slots:
             if r is req:
-                if (req.last_logits is None
+                ready = (req.pending if self.mode == "paged"
+                         else req.last_logits)
+                if (ready is None
                         or req.prefill_pos < len(req.prefill_tokens)):
                     return "prefilling"
                 return "decoding"
@@ -774,6 +805,7 @@ class ServeEngine:
                 "prompt": [int(t) for t in r.prompt],
                 "max_new_tokens": r.max_new_tokens,
                 "output": [int(t) for t in r.output],
+                "pending": r.pending,
                 "prefill_tokens": [int(t) for t in r.prefill_tokens],
                 "prefill_pos": r.prefill_pos,
                 "admit_seq": r.admit_seq,
@@ -912,6 +944,7 @@ class ServeEngine:
             lg = arrays.get(f"logits/{req.rid}")
             if lg is not None:
                 req.last_logits = np.asarray(lg)
+            req.pending = r.get("pending")
             by_rid[req.rid] = req
 
         sched = host["sched"]
@@ -948,6 +981,17 @@ class ServeEngine:
         # non-terminal timeline from a prior run of this engine is
         # discarded), so their spans terminate cleanly on retire
         self.obs.on_restore(sorted(by_rid))
+        # a snapshot from before the engine sampled inside its step holds
+        # a ready lane's logits and no pending id: draw the token its next
+        # decode step would have drawn, so the lane resumes decoding
+        tnow = self._clock()
+        for r in host["requests"]:
+            req = by_rid[r["rid"]]
+            if ("pending" not in r and req.last_logits is not None
+                    and req.prefill_pos >= len(req.prefill_tokens)
+                    and not self._should_retire(req, limit_only=False)):
+                self._emit_token(req, self._sample_next(req), tnow)
+                req.pending = req.output[-1]
 
     def save_snapshot(self, directory: str, step: int) -> str:
         """Persist :meth:`snapshot` through ``repro.ckpt`` (manifest +
@@ -1061,23 +1105,28 @@ class ServeEngine:
         with self.obs.annotate("serve.tables"):
             bt, _ = self.alloc.device_tables(self._table_shardings)
         with self.obs.annotate("serve.prefill_chunk"):
-            logits, self.pages = self._prefill_paged(
+            out, self.pages = self._prefill_paged(
                 self.params, self.pages, bt, jnp.asarray(tokens),
-                jnp.asarray(pos0), jnp.asarray(seq_lens))
+                jnp.asarray(pos0), jnp.asarray(seq_lens), self.key)
             with self.obs.annotate("serve.logits_to_host"):
-                lg = np.asarray(logits)  # host sync: the chunk has landed
+                ids, finite, lg = self._land(out)
         with self.obs.annotate("serve.lane_update"):
-            self._prefill_lanes(lanes, lg, t0)
+            prefilled = self._prefill_lanes(lanes, finite, t0)
+        with self.obs.annotate("serve.sample"):
+            self._emit(prefilled, ids, lg)
 
-    def _prefill_lanes(self, lanes, lg: np.ndarray, t0: float) -> None:
-        """Per-lane bookkeeping after a prefill chunk landed."""
+    def _prefill_lanes(self, lanes, finite: np.ndarray,
+                       t0: float) -> List[Tuple[int, Request]]:
+        """Per-lane bookkeeping after a prefill chunk landed; returns the
+        lanes whose prompt is now wholly prefilled on finite logits."""
         self.obs.on_prefill(
             [(slot, self.sched.slot_req[slot].rid, n)
              for slot, n in lanes], t0)
         self._charge_prefill(
             [self.sched.slot_req[slot].rid for slot, _ in lanes])
-        fault_slot, lg = self._inject_lane_chaos(
-            [s for s, _ in lanes], lg)
+        fault_slot, finite = self._inject_lane_chaos(
+            [s for s, _ in lanes], finite)
+        prefilled = []
         for slot, n_real in lanes:
             req = self.sched.slot_req[slot]
             if slot == fault_slot:
@@ -1088,50 +1137,87 @@ class ServeEngine:
             req.prefill_pos += n_real
             self.alloc.pos[slot] += n_real
             if req.prefill_pos >= len(req.prefill_tokens):
-                last = lg[slot, -1]
-                if not np.all(np.isfinite(last)):
+                if not finite[slot]:
                     # non-finite logits must be caught *before* the
                     # prefix-cache insert: poisoned KV pages must never
                     # be published for other requests to share
                     self._fault(slot, req, "nan_logits")
                     continue
-                req.last_logits = last
                 if self.prefix_cache is not None:
                     # the prompt's full pages are write-frozen from here
                     # (decode appends at pos >= len(prefill_tokens)):
                     # publish them for other requests to share
                     self.prefix_cache.insert(req.prefill_tokens,
                                              self.alloc.block_row(slot))
+                prefilled.append((slot, req))
+        return prefilled
 
     def _decode_once_paged(self) -> None:
         with self.obs.annotate("serve.schedule"):
             ready = self._grant_decode()
         if not ready:
             return
-        with self.obs.annotate("serve.sample"):
-            updates: Dict[int, int] = {}
-            tnow = self._clock()
-            for slot, req in ready:
-                tok = self._sample_next(req)
-                if not req.output and req.ttft is None:
-                    req.ttft = tnow - req.submit_t
-                    self.obs.on_first_token(req.rid, req.ttft, tnow)
-                else:
-                    self.obs.on_token(req.rid, tnow)
-                req.output.append(tok)
-                updates[slot] = tok
-            tokens = self._lane_tokens(updates)
-            active = jnp.asarray(self.sched.lane_mask(updates))
         t0 = self.obs.now()
         with self.obs.annotate("serve.tables"):
             bt, pos = self.alloc.device_tables(self._table_shardings)
+            tokens = self._lane_tokens({s: r.pending for s, r in ready})
+            active = jnp.asarray(
+                self.sched.lane_mask([s for s, _ in ready]))
         with self.obs.annotate("serve.decode_step"):
-            logits, self.pages = self._decode_paged(
-                self.params, self.pages, bt, pos, active, tokens)
+            out, self.pages = self._decode_paged(
+                self.params, self.pages, bt, pos, active, tokens, self.key)
             with self.obs.annotate("serve.logits_to_host"):
-                lg = np.asarray(logits)  # host sync: the step has landed
+                ids, finite, lg = self._land(out)
         with self.obs.annotate("serve.lane_update"):
-            self._decode_lanes(ready, lg, t0)
+            decoded = self._decode_lanes(ready, finite, t0)
+        with self.obs.annotate("serve.sample"):
+            self._emit(decoded, ids, lg)
+
+    def _land(self, out: Sampled):
+        """The host's one sync per dispatch: ``(ids, finite, None)``, or
+        ``(None, finite, logits)`` when ``_sample_next`` is overridden and
+        the host picks the tokens from its own copy of the logits."""
+        self.key = out.key
+        if self._host_sampling():
+            finite, lg = jax.device_get((out.finite, out.logits))
+            return None, finite, lg
+        ids, finite = jax.device_get((out.ids, out.finite))
+        return ids, finite, None
+
+    def _host_sampling(self) -> bool:
+        """True when ``_sample_next`` is overridden, on the instance or
+        its class (teacher forcing, a recording hook): it is then called
+        once per lane per token on a host copy of that lane's logits."""
+        return (getattr(self._sample_next, "__func__", None)
+                is not _HOST_SAMPLE_NEXT)
+
+    def _emit(self, lanes: List[Tuple[int, Request]],
+              ids: Optional[np.ndarray], lg: Optional[np.ndarray]) -> None:
+        """Append each landed lane's next id to its output; the next
+        decode step feeds it.  A lane due to retire (length limit or
+        ``max_len``) takes none."""
+        tnow = self._clock()
+        n = 0
+        for slot, req in lanes:
+            if lg is not None:
+                req.last_logits = lg[slot, -1]
+            if self._should_retire(req, limit_only=False):
+                continue
+            tok = int(ids[slot]) if lg is None else self._sample_next(req)
+            self._emit_token(req, tok, tnow)
+            req.pending = tok
+            n += 1
+        if n:
+            self.obs.on_sampled("device" if lg is None else "host", n)
+
+    def _emit_token(self, req: Request, tok: int, tnow: float) -> None:
+        """Append one generated token, stamping TTFT on the first."""
+        if not req.output and req.ttft is None:
+            req.ttft = tnow - req.submit_t
+            self.obs.on_first_token(req.rid, req.ttft, tnow)
+        else:
+            self.obs.on_token(req.rid, tnow)
+        req.output.append(tok)
 
     def _grant_decode(self) -> List[Tuple[int, Request]]:
         """The decoding lanes granted a page for this step, charged to
@@ -1140,7 +1226,7 @@ class ServeEngine:
         # this step and resumes via re-prefill with identical greedy state
         ready = []
         for slot, req in self.sched.decode_lanes():
-            if len(req.output) >= req.max_new_tokens:
+            if self._should_retire(req, limit_only=False):
                 continue
             if self.sched.slot_req[slot] is not req:
                 continue  # preempted by an earlier lane's grant this loop
@@ -1154,33 +1240,37 @@ class ServeEngine:
         return ready
 
     def _decode_lanes(self, ready: List[Tuple[int, Request]],
-                      lg: np.ndarray, t0: float) -> None:
-        """Per-lane bookkeeping after a decode step landed."""
+                      finite: np.ndarray,
+                      t0: float) -> List[Tuple[int, Request]]:
+        """Per-lane bookkeeping after a decode step landed; returns the
+        lanes whose step wrote its K/V on finite logits."""
         self.obs.on_decode([(s, r.rid) for s, r in ready], t0)
         self._charge_decode([r.rid for _, r in ready])
-        fault_slot, lg = self._inject_lane_chaos(
-            [s for s, _ in ready], lg)
+        fault_slot, finite = self._inject_lane_chaos(
+            [s for s, _ in ready], finite)
+        decoded = []
         for slot, req in ready:
             if slot == fault_slot:
                 self._fault(slot, req, "step_fault")
                 continue
-            last = lg[slot, -1]
-            if not np.all(np.isfinite(last)):
-                # the token appended above was sampled from *valid*
-                # logits and its KV write landed; the recompute retry
-                # replays it, so greedy output is unchanged — only the
-                # poisoned logits are discarded
+            if not finite[slot]:
+                # the step's id for this lane came from non-finite logits
+                # and is dropped, never appended; the id it fed is already
+                # in ``req.output``, so the recompute retry prefills it
+                # back and draws the next id again (greedy output is
+                # unchanged)
                 self._fault(slot, req, "nan_logits")
                 continue
             self.alloc.pos[slot] += 1
-            req.last_logits = last
+            decoded.append((slot, req))
+        return decoded
 
     def _retire_paged(self, limit_only: bool = False) -> List[Request]:
         done = []
         for slot, req in enumerate(self.sched.slot_req):
             if req is None or req.prefill_pos < len(req.prefill_tokens):
                 # mid-prefill: even a max_new_tokens=0 request finishes
-                # its prefill first and retires holding its prompt logits
+                # its prefill first (and publishes its prompt's pages)
                 continue
             if self._should_retire(req, limit_only):
                 req.done = True
@@ -1194,22 +1284,22 @@ class ServeEngine:
         return done
 
     # ============================================== faults / quarantine
-    def _inject_lane_chaos(self, slots: List[int], lg: np.ndarray):
+    def _inject_lane_chaos(self, slots: List[int], finite: np.ndarray):
         """Consult the chaos injector after a dispatch landed: returns
-        ``(fault_slot, lg)`` where ``fault_slot`` (or None) takes a
-        simulated device error, and ``lg`` may have one lane's logits
-        overwritten with NaN (a copy — the injected poison then flows
-        through the same non-finite detection a real fault would)."""
+        ``(fault_slot, finite)`` where ``fault_slot`` (or None) takes a
+        simulated device error, and ``finite`` may have one lane's flag
+        cleared (a copy — the injected poison then takes the same path
+        as logits the step found non-finite)."""
         if self.chaos is None or not slots:
-            return None, lg
+            return None, finite
         fault_slot = None
         if self.chaos.fire("step_fault"):
             fault_slot = slots[self.chaos.pick("step_fault", len(slots))]
         if self.chaos.fire("nan_logits"):
             victim = slots[self.chaos.pick("nan_logits", len(slots))]
-            lg = np.array(lg)  # np.asarray of a jax array may be read-only
-            lg[victim] = np.nan
-        return fault_slot, lg
+            finite = np.array(finite)  # a fetched array may be read-only
+            finite[victim] = False
+        return fault_slot, finite
 
     def _scrub_slot_pages(self, slot: int) -> None:
         """Zero a faulted lane's privately-owned pages before they return
@@ -1264,6 +1354,7 @@ class ServeEngine:
         req.prefill_pos = 0
         req.cached_tokens = 0
         req.last_logits = None
+        req.pending = None
         req.retries += 1
         self.retried += 1
         self.sched.queue.appendleft(req)
@@ -1397,15 +1488,11 @@ class ServeEngine:
             if len(req.output) >= req.max_new_tokens:
                 continue
             tok = self._sample_next(req)
-            if not req.output and req.ttft is None:
-                req.ttft = tnow - req.submit_t
-                self.obs.on_first_token(req.rid, req.ttft, tnow)
-            else:
-                self.obs.on_token(req.rid, tnow)
-            req.output.append(tok)
+            self._emit_token(req, tok, tnow)
             updates[slot] = tok
         if not updates:
             return
+        self.obs.on_sampled("host", len(updates))
         tokens = self._slot_tokens(updates)
         keep = jnp.asarray([s in updates for s in range(self.n_slots)])
         t0 = self.obs.now()
@@ -1432,12 +1519,13 @@ class ServeEngine:
 
     # ------------------------------------------------------------ shared
     def _sample_next(self, req: Request) -> int:
-        """Sample the next token from a request's last logits.
+        """Sample the next token from a request's host ``last_logits``.
 
-        ``last_logits`` is ``(V,)``, or ``(K, V)`` for audio — the engine's
-        token stream carries one id broadcast across codebooks, so the
-        audio path samples codebook 0 (the seed engine crashed here trying
-        to scalar-convert a (K,) sample).
+        The host sampler: slots mode calls it, and the paged engine calls
+        it only where it is overridden (its own dispatches sample inside
+        the jit).  ``last_logits`` is ``(V,)``, or ``(K, V)`` for audio —
+        the engine's token stream carries one id broadcast across
+        codebooks, so the audio path samples codebook 0.
         """
         last = jnp.asarray(req.last_logits)
         if last.ndim == 1:
@@ -1452,3 +1540,7 @@ class ServeEngine:
             return limit
         overflow = len(req.prompt) + len(req.output) >= self.max_len - 1
         return limit or overflow
+
+
+# the unmodified host sampler: ``_host_sampling`` tells an override by it
+_HOST_SAMPLE_NEXT = ServeEngine._sample_next

@@ -263,7 +263,7 @@ class PagedScheduler:
             (s, r) for s, r in enumerate(self.slot_req)
             if r is not None
             and r.prefill_pos >= len(r.prefill_tokens)
-            and r.last_logits is not None
+            and r.pending is not None
         ]
 
     def lane_mask(self, slots) -> np.ndarray:
@@ -315,6 +315,7 @@ class PagedScheduler:
         req.prefill_pos = 0
         req.cached_tokens = 0
         req.last_logits = None
+        req.pending = None
         req.preemptions += 1
         self.preemptions += 1
         self.drop_forks(slot)

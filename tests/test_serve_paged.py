@@ -4,6 +4,7 @@ allocator/free-list behaviour, chunked-prefill numerics, preemption, and
 int8 KV pages through the ``EnginePlan.kv_bits`` knob."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -229,8 +230,10 @@ def test_capacity_admission_queues(rng):
 def test_zero_token_request_finishes_its_prefill(rng):
     """A ``max_new_tokens=0`` request whose prompt spans several prefill
     chunks stays resident until its last chunk lands, then retires with
-    an empty output holding the prompt's last-position logits — the same
-    logits a one-shot forward gives."""
+    an empty output.  With a host ``_sample_next`` set, the engine keeps
+    each landed lane's logits on the host: the request retires holding
+    the prompt's last-position logits — the same logits a one-shot
+    forward gives."""
     from repro.models import forward
 
     cfg = reduced_f32("qwen2.5-3b")
@@ -239,6 +242,7 @@ def test_zero_token_request_finishes_its_prefill(rng):
     scfg = ServeConfig(max_new_tokens=0)
     eng = ServeEngine(cfg, params, scfg, n_slots=2, max_len=32,
                       mode="paged", page_size=4, prefill_chunk=3)
+    eng._sample_next = functools.partial(ServeEngine._sample_next, eng)
     req = eng.submit(prompt)
     other = eng.submit([4, 5], max_new_tokens=3)
     eng.run()

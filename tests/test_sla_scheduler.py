@@ -67,27 +67,40 @@ def test_budget_output_identical_to_fcfs(rng):
 def test_per_step_token_budget_never_exceeded(rng):
     """Hard invariant: prefill tokens + decode tokens per engine step
     never exceed ``step_tokens``, across admission waves, long prompts
-    and lanes completing prefill mid-step (the +1 completion reserve)."""
+    and lanes completing prefill mid-step (the +1 completion reserve:
+    such a lane's first token comes from its last chunk, and it decodes
+    that token in the same step).  Decode tokens are the lanes each
+    decode dispatch runs."""
+    import numpy as np
+
     cfg = reduced_f32("qwen2.5-3b")
     params = init_params(cfg, rng)
     step_tokens = 7
     eng = _engine(cfg, params, step_tokens=step_tokens, n_slots=3,
                   max_new=4, prefill_chunk=5)
+    decoded = [0]
+    dec = eng._decode_paged
+
+    def counted(params, pages, bt, pos, active, *rest):
+        decoded[0] += int(np.asarray(active).sum())
+        return dec(params, pages, bt, pos, active, *rest)
+
+    eng._decode_paged = counted
     reqs = [eng.submit(list(range(1, 1 + n)), max_new_tokens=4)
             for n in (29, 3, 17, 1, 40, 6)]
-    prev_prefill, prev_out = 0, 0
+    prev_prefill, prev_dec = 0, 0
     steps = 0
     while eng.has_work():
         eng.step()
         steps += 1
         assert steps < 500, "scheduler stopped making progress"
-        prefill_now = eng.prefill_computed
-        out_now = sum(len(r.output) for r in reqs)
-        spent = (prefill_now - prev_prefill) + (out_now - prev_out)
+        prefill_now, dec_now = eng.prefill_computed, decoded[0]
+        spent = (prefill_now - prev_prefill) + (dec_now - prev_dec)
         assert spent <= step_tokens, \
             f"step {steps} spent {spent} > budget {step_tokens}"
-        prev_prefill, prev_out = prefill_now, out_now
-    assert all(r.done for r in reqs)
+        prev_prefill, prev_dec = prefill_now, dec_now
+    assert decoded[0] > 0
+    assert all(r.done and len(r.output) == 4 for r in reqs)
 
 
 def test_small_budget_still_makes_progress(rng):
@@ -122,7 +135,7 @@ def test_decode_advances_every_step_during_long_prefill(rng):
                   prefill_chunk=8)
     short = eng.submit([1, 2, 3], max_new_tokens=12)
     # let the short request reach decode
-    while short.last_logits is None:
+    while eng.request_phase(short) != "decoding":
         eng.step()
     long = eng.submit(list(range(100, 160)), max_new_tokens=2)
     overlap_steps = 0

@@ -94,8 +94,8 @@ def test_budget_scheduler_vtime_restored(model):
 
 def test_snapshot_excludes_terminal_requests(model):
     cfg, params = model
-    eng = _engine(cfg, params, max_new=2)
-    done_req = eng.submit([7, 8])
+    eng = _engine(cfg, params)
+    done_req = eng.submit([7, 8], max_new_tokens=2)
     eng.run()
     assert done_req.done
     _submit_all(eng)
@@ -121,6 +121,36 @@ def test_every_pending_state_is_captured(model):
     ref = _drain(eng)
     engB = _engine(cfg, params, prefix_cache=True)
     engB.restore(snap)
+    assert _drain(engB) == ref
+
+
+def test_snapshot_without_pending_ids_resumes(model):
+    """A snapshot in the format from before the engine sampled inside its
+    step holds a ready lane's logits and no pending id (the newest id not
+    yet drawn).  Restore draws that id from the logits, so the drained
+    outputs equal the uninterrupted run's."""
+    import functools
+
+    cfg, params = model
+    engA = _engine(cfg, params)
+    # the host path keeps each ready lane's logits, as that format did
+    engA._sample_next = functools.partial(ServeEngine._sample_next, engA)
+    _submit_all(engA)
+    for _ in range(3):
+        engA.step()
+    snap = engA.snapshot()
+    ref = _drain(engA)
+    ready = 0
+    for r in snap["host"]["requests"]:
+        pending = r.pop("pending")
+        if pending is not None:
+            assert r["output"].pop() == pending
+            assert f"logits/{r['rid']}" in snap["arrays"]
+            ready += 1
+    assert ready
+    engB = _engine(cfg, params)
+    engB.restore(snap)
+    engB.audit()
     assert _drain(engB) == ref
 
 
