@@ -2,8 +2,11 @@
 
 Everything that belongs to one cell is found by name from
 ``BENCHMARK.json``: its configuration file (``configs[].file``), its
-traffic file (``bench/traffic/<workload>.json``) and one reader file per
-metric (``bench/metrics/<metric>.py``).  The harness builds the program's
+traffic file (``bench/traffic/<workload>.json``), one reader file per
+metric (``bench/metrics/<metric>.py``) and the architecture module that
+the configuration names (``bench/yardstick/arch/<name>.py``), which gives
+the sizes, the weights, the reference and the modelled work of the block;
+the harness itself knows no block.  It builds the program's
 ``ServeEngine`` from the configuration, makes the weights on the device
 from the seed, warms the cell's two step shapes, drives the engine with
 the cell's traffic for the window, and then decides ``correct`` against
@@ -39,8 +42,7 @@ from typing import Dict, List, Optional, Tuple
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-from bench.yardstick import model as ymodel  # noqa: E402
-from bench.yardstick import reference, traffic  # noqa: E402
+from bench.yardstick import arch, reference, traffic  # noqa: E402
 from bench.yardstick.peaks import peaks  # noqa: E402
 
 # the program's own paths one step below the configured precision
@@ -74,8 +76,13 @@ class Cell:
     per_layer: List[str]
 
     @property
-    def dims(self) -> ymodel.Dims:
-        return ymodel.dims(self.cfg)
+    def arch(self):
+        """The architecture module the configuration file names."""
+        return arch.of(self.cfg)
+
+    @property
+    def dims(self):
+        return self.arch.dims(self.cfg)
 
     @property
     def serve(self) -> dict:
@@ -95,7 +102,8 @@ def load_cell(workload: str, root: str = ROOT) -> Cell:
                          f"{sorted(cells)}")
     w = cells[workload]
     cfg_entry = next(c for c in bench["configs"] if c["name"] == w["config"])
-    cfg = ymodel.load(root, cfg_entry["file"])
+    with open(os.path.join(root, cfg_entry["file"])) as f:
+        cfg = json.load(f)
     traffic_file = os.path.join(root, "bench", "traffic", f"{workload}.json")
     with open(traffic_file) as f:
         tr = json.load(f)
@@ -119,33 +127,14 @@ def reader(name: str, root: str = ROOT):
 
 # ------------------------------------------------------- program build
 def program_config(cell: Cell):
-    """The program's ``ModelConfig`` for the cell, checked against the
-    configuration file's published numbers."""
+    """The program's ``ModelConfig`` for the cell, checked against every
+    field the configuration's architecture module names."""
     from repro.config import get_arch
 
     prog = cell.cfg["program"]
     mc = dataclasses.replace(get_arch(prog["arch"]),
                              **prog.get("overrides", {}))
-    d = cell.dims
-    have = {
-        "num_hidden_layers": (mc.n_layers, d.n_layers),
-        "hidden_size": (mc.d_model, d.d_model),
-        "num_attention_heads": (mc.n_heads, d.n_heads),
-        "num_key_value_heads": (mc.n_kv_heads, d.n_kv_heads),
-        "head_dim": (mc.resolved_head_dim, d.head_dim),
-        "intermediate_size": (mc.d_ff, d.d_ff),
-        "vocab_size": (mc.vocab_size, d.vocab),
-        "mlp gated": (mc.mlp_gated, d.gated),
-        "qkv bias": (mc.qkv_bias, d.qkv_bias),
-        "tie_word_embeddings": (mc.tie_embeddings, d.tied),
-        "rope_theta": (mc.rope_theta, d.rope_theta),
-        "norm eps": (mc.norm_eps, d.norm_eps),
-        "sliding_window": (mc.sliding_window if not mc.global_every
-                           else -1, d.window),
-        "itemsize": ({"bfloat16": 2, "float16": 2, "float32": 4}[mc.dtype],
-                     d.itemsize),
-        "family": (mc.family, "dense"),
-    }
+    have = cell.arch.program_fields(mc, cell.dims)
     bad = {k: v for k, v in have.items() if v[0] != v[1]}
     if bad:
         raise SystemExit(f"the program's {prog['arch']!r} departs from the "
@@ -159,9 +148,9 @@ def make_params(cell: Cell, seed: int, mesh):
 
     from bench.yardstick import weights
 
-    d = cell.dims
+    d, params = cell.dims, cell.arch.params
     key = weights.seed_key(seed)
-    fn = lambda k: weights.params(d, k)  # noqa: E731
+    fn = lambda k: params(d, k)  # noqa: E731
     if mesh is None:
         out = jax.jit(fn)(key)
     else:
@@ -425,7 +414,7 @@ class Run:
     """What a metric reader sees."""
 
     cell: Cell
-    dims: ymodel.Dims
+    dims: object           # the architecture module's sizes
     peak: dict
     chips: int
     window: Window
@@ -433,6 +422,10 @@ class Run:
     setup_s: float
     lateness: List[float]
     trace: Optional[object] = None     # trace.Reduced, traced runs only
+
+    @property
+    def arch(self):
+        return self.cell.arch
 
     @property
     def window_recs(self) -> List[Rec]:
@@ -523,7 +516,7 @@ def check(cell: Cell, seed: int, served, log=print,
     chosen = reference.sample(served, seed, ref["tokens"], ref["rows"])
     targets = (teacher_forced(cell, seed, chosen, control, log)
                if control and chosen else None)
-    gaps = (reference.served_gaps(cell.dims, seed, chosen,
+    gaps = (cell.arch.served_gaps(cell.dims, seed, chosen,
                                   tokens=ref["tokens"], rows=ref["rows"],
                                   q_block=ref["q_block"], targets=targets)
             if chosen else np.zeros(0))

@@ -1,4 +1,4 @@
-"""The yardstick's FLOPs arithmetic (``bench/yardstick/costs.py``)
+"""The yardstick's FLOPs arithmetic (``bench/yardstick/arch/dense.py``)
 against XLA's compiled counts, and its independence from ``max_len``.
 
 XLA's ``cost_analysis`` counts a ``while`` body once, so the compiled
@@ -18,7 +18,8 @@ import pytest
 
 import _tiny
 from bench import harness
-from bench.yardstick import costs, weights
+from bench.yardstick import weights
+from bench.yardstick.arch import dense
 
 B, PAGE, NBLK = 4, 8, 4
 T = NBLK * PAGE
@@ -28,12 +29,12 @@ TOL = 0.05
 def _setup(config, window=0):
     from repro.serve.pages import init_kv_pages
 
-    c = _tiny.cell(config, window=window)
+    c = _tiny.cell(config, window=window, norm=_tiny.PROGRAM_NORM)
     c.cfg["num_hidden_layers"] = 1
     c.cfg["program"]["overrides"]["n_layers"] = 1
     d = c.dims
     mc = harness.program_config(c)
-    params = jax.jit(lambda k: weights.params(d, k))(weights.seed_key(1))
+    params = jax.jit(lambda k: dense.params(d, k))(weights.seed_key(1))
     pages = init_kv_pages(mc, B * NBLK + 1, PAGE)
     bt = jnp.arange(1, 1 + B * NBLK, dtype=jnp.int32).reshape(B, NBLK)
     return d, mc, params, pages, bt
@@ -57,9 +58,9 @@ def test_decode_flops_match_compiled(config):
     measured = _xla_flops(fn, params, pages, bt,
                           jnp.full((B,), T - 1, jnp.int32),
                           jnp.ones((B,), bool), jnp.zeros((B, 1), jnp.int32))
-    modeled, _ = costs.decode_step(d, [T] * B, PAGE)
+    modeled, _ = dense.decode_step(d, [T] * B, PAGE)
     assert 1 - TOL <= modeled / measured <= 1 + TOL, (modeled, measured)
-    half, _ = costs.decode_step(d, [T // 2] * B, PAGE)
+    half, _ = dense.decode_step(d, [T // 2] * B, PAGE)
     assert half < modeled
 
 
@@ -76,30 +77,30 @@ def test_prefill_flops_match_compiled(config):
                           jnp.zeros((B, 1), jnp.int32),
                           jnp.full((B,), T - 1, jnp.int32),
                           jnp.full((B,), T, jnp.int32))
-    modeled, _ = costs.prefill_step(d, [(T - 1, 1)] * B, PAGE)
+    modeled, _ = dense.prefill_step(d, [(T - 1, 1)] * B, PAGE)
     assert 1 - TOL <= modeled / measured <= 1 + TOL, (modeled, measured)
 
 
 def test_weight_bytes_are_the_tree_read_per_step():
     d = _tiny.cell("starcoder2-15b-tp4", dtype="bfloat16").dims
-    shapes = jax.eval_shape(lambda k: weights.params(d, k),
+    shapes = jax.eval_shape(lambda k: dense.params(d, k),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
     total = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes))
     embed = d.vocab * d.d_model * d.itemsize      # untied: gathered only
-    assert costs.weight_bytes(d) == total - embed
+    assert dense.weight_bytes(d) == total - embed
     q = _tiny.cell("qwen2.5-3b", dtype="bfloat16").dims
-    qs = jax.eval_shape(lambda k: weights.params(q, k),
+    qs = jax.eval_shape(lambda k: dense.params(q, k),
                         jax.ShapeDtypeStruct((2,), jnp.uint32))
-    assert costs.weight_bytes(q) == sum(
+    assert dense.weight_bytes(q) == sum(
         a.size * a.dtype.itemsize for a in jax.tree.leaves(qs))
 
 
 def test_window_caps_the_keys():
     d = _tiny.cell("starcoder2-15b-tp4", window=24).dims
-    assert costs.attn_decode(d, [100], PAGE) == costs.attn_decode(d, [24],
-                                                                  PAGE)
-    f_long, _ = costs.attn_prefill(d, [(90, 10)], PAGE)
-    f_ref, _ = costs.attn_prefill(d, [(30, 10)], PAGE)
+    assert dense.attn_decode(d, [100], PAGE) == dense.attn_decode(d, [24],
+                                                                 PAGE)
+    f_long, _ = dense.attn_prefill(d, [(90, 10)], PAGE)
+    f_ref, _ = dense.attn_prefill(d, [(30, 10)], PAGE)
     assert f_long == f_ref
 
 
@@ -111,7 +112,7 @@ def _served_work(max_len):
 
     c = _tiny.cell()
     d = c.dims
-    params = jax.jit(lambda k: weights.params(d, k))(weights.seed_key(4))
+    params = jax.jit(lambda k: dense.params(d, k))(weights.seed_key(4))
     scfg = ServeConfig(mode="paged", page_size=8, prefill_chunk=16,
                        prefix_cache=True, sched="budget", step_tokens=36,
                        engine=EngineConfig(attn_backend="gather"))
@@ -124,8 +125,8 @@ def _served_work(max_len):
             0, d.vocab, n).tolist(), m), 0.0)
     while eng.has_work():
         tr.step()
-    return [(costs.decode_step(d, s.decode, 8),
-             costs.prefill_step(d, s.prefill, 8)) for s in tr.steps]
+    return [(dense.decode_step(d, s.decode, 8),
+             dense.prefill_step(d, s.prefill, 8)) for s in tr.steps]
 
 
 def test_padded_max_len_does_not_change_modelled_work():

@@ -64,7 +64,7 @@ def test_unchanged_state_is_caught(monkeypatch):
 def test_missing_exchange_is_caught(monkeypatch):
     from repro.models import layers, transformer
 
-    c = _tiny.cell("starcoder2-15b-tp4")
+    c = _tiny.cell("starcoder2-15b-tp4", norm=_tiny.PROGRAM_NORM)
     d = c.dims
     row_in = {d.n_heads * d.head_dim, d.d_ff}
     dense = layers.dense

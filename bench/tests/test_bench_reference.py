@@ -17,6 +17,7 @@ import pytest
 import _tiny
 from bench import harness
 from bench.yardstick import reference, weights
+from bench.yardstick.arch import dense
 
 CASES = [("qwen2.5-3b", 0), ("starcoder2-15b-tp4", 0),
          ("starcoder2-15b-tp4", 24)]
@@ -25,9 +26,9 @@ CASES = [("qwen2.5-3b", 0), ("starcoder2-15b-tp4", 0),
 def test_reference_layer_weights_are_the_served_weights():
     d = _tiny.cell(dtype="bfloat16").dims
     key = weights.seed_key(2 ** 33 + 1)
-    stacked = jax.jit(lambda k: weights.params(d, k))(key)
+    stacked = jax.jit(lambda k: dense.params(d, k))(key)
     for i in range(d.n_layers):
-        one = jax.jit(lambda k, j: weights.layer(d, k, j))(key, jnp.int32(i))
+        one = jax.jit(lambda k, j: dense.layer(d, k, j))(key, jnp.int32(i))
         for a, b in zip(jax.tree.leaves(one),
                         jax.tree.leaves(stacked["layers"])):
             assert a.dtype == jnp.bfloat16
@@ -38,10 +39,10 @@ def test_reference_layer_weights_are_the_served_weights():
 def test_reference_logits_match_program_forward(config, window):
     from repro.models import forward
 
-    c = _tiny.cell(config, window=window)
+    c = _tiny.cell(config, window=window, norm=_tiny.PROGRAM_NORM)
     d = c.dims
     key = weights.seed_key(3)
-    params = jax.jit(lambda k: weights.params(d, k))(key)
+    params = jax.jit(lambda k: dense.params(d, k))(key)
     seq = np.random.default_rng(0).integers(0, d.vocab, 48).tolist()
     got, _ = forward(params, {"tokens": jnp.asarray([seq])},
                      harness.program_config(c), None)
@@ -49,10 +50,10 @@ def test_reference_logits_match_program_forward(config, window):
     # served token j is predicted at position j: compare the gap of the
     # program's own argmax at every position
     served = [int(t) for t in got.argmax(-1)]
-    gaps = reference.served_gaps(d, 3, [(seq[:1], served[:1])],
+    gaps = dense.served_gaps(d, 3, [(seq[:1], served[:1])],
                                  tokens=64, rows=64, q_block=64)
     assert gaps.shape == (1,)
-    full = reference.served_gaps(
+    full = dense.served_gaps(
         d, 3, [(seq[:1], seq[1:] + [served[-1]])], tokens=64, rows=64,
         q_block=64)
     # the program's logit of the true next token vs the reference's
@@ -63,7 +64,8 @@ def test_reference_logits_match_program_forward(config, window):
 
 @pytest.mark.parametrize("config,window", CASES)
 def test_served_tokens_agree_with_reference(config, window, monkeypatch):
-    out = _tiny.run(_tiny.cell(config, window=window), monkeypatch)
+    out = _tiny.run(_tiny.cell(config, window=window,
+                               norm=_tiny.PROGRAM_NORM), monkeypatch)
     gap = out["checks"]["mean_gap"]
     assert out["checks"]["served_compared"]["value"] >= 10
     assert gap["value"] <= gap["limit"], gap
@@ -93,7 +95,7 @@ def test_forced_program_puts_its_own_tokens_first():
     them first again: the forced control reads the served positions."""
     c = _tiny.cell()
     d = c.dims
-    params = jax.jit(lambda k: weights.params(d, k))(weights.seed_key(8))
+    params = jax.jit(lambda k: dense.params(d, k))(weights.seed_key(8))
     eng = harness.build_engine(c, params, None, trace=False, control=None)
     rng = np.random.default_rng(8)
     reqs = [eng.submit(rng.integers(0, d.vocab, n).tolist(), m)

@@ -29,3 +29,11 @@ def peaks(device_kind: str) -> Dict[str, float]:
     except KeyError:
         raise KeyError(f"no published peaks for device kind {device_kind!r};"
                        f" known: {sorted(PEAKS)}") from None
+
+
+def least_time(flops: float, nbytes: float, peak: dict, chips: int):
+    """The least seconds ``chips`` chips need for the work, and which
+    resource binds (``"flops"`` or ``"hbm"``)."""
+    tf = flops / (chips * peak["flops"])
+    tb = nbytes / (chips * peak["hbm_bw"])
+    return (tf, "flops") if tf >= tb else (tb, "hbm")

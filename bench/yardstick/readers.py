@@ -5,15 +5,15 @@ metric out of the result line.
 
 Clock readings are the harness's own (``time.perf_counter`` around each
 ``ServeEngine.step()``); device times come from the trace reduction; the
-work credited to a step or kernel comes from :mod:`bench.yardstick.costs`
-at the lanes' real contexts.
+work credited to a step or kernel comes from the cell's architecture
+module (:mod:`bench.yardstick.arch`) at the lanes' real contexts.
 """
 
 from __future__ import annotations
 
 from typing import List, Optional
 
-from bench.yardstick import costs
+from bench.yardstick.peaks import least_time
 from bench.yardstick.stats import percentile
 
 
@@ -77,7 +77,7 @@ def _step_share(run, span: str, work) -> Optional[float]:
     busy = red.busy_in(span)
     if not busy or red.span_count(span) != len(work):
         return None
-    least = sum(costs.least_time(f, b, run.peak, run.chips)[0]
+    least = sum(least_time(f, b, run.peak, run.chips)[0]
                 for f, b in work)
     return 100.0 * least / busy
 
@@ -87,7 +87,7 @@ def decode_mfu_pct(run) -> Optional[float]:
     resource of each step) over the device-busy time inside the
     program's ``serve.decode_step`` spans."""
     page = run.cell.serve["page_size"]
-    work = [costs.decode_step(run.dims, s.decode, page)
+    work = [run.arch.decode_step(run.dims, s.decode, page)
             for s in run.window.steps if s.decode]
     return _step_share(run, "serve.decode_step", work)
 
@@ -95,7 +95,7 @@ def decode_mfu_pct(run) -> Optional[float]:
 def prefill_mfu_pct(run) -> Optional[float]:
     """The same for the prefill calls and ``serve.prefill_chunk``."""
     page = run.cell.serve["page_size"]
-    work = [costs.prefill_step(run.dims, s.prefill, page)
+    work = [run.arch.prefill_step(run.dims, s.prefill, page)
             for s in run.window.steps if s.prefill]
     return _step_share(run, "serve.prefill_chunk", work)
 
@@ -107,21 +107,21 @@ def _kernel_share(run, kernel: str, work) -> Optional[float]:
     t = red.op_time(kernel)
     if not t:
         return None
-    least = sum(costs.least_time(f, b, run.peak, run.chips)[0]
+    least = sum(least_time(f, b, run.peak, run.chips)[0]
                 for f, b in work)
     return 100.0 * least / t
 
 
 def attn_decode_roofline_pct(run) -> Optional[float]:
     page = run.cell.serve["page_size"]
-    work = [costs.attn_decode(run.dims, s.decode, page)
+    work = [run.arch.attn_decode(run.dims, s.decode, page)
             for s in run.window.steps if s.decode]
     return _kernel_share(run, "paged_attention_decode", work)
 
 
 def attn_prefill_roofline_pct(run) -> Optional[float]:
     page = run.cell.serve["page_size"]
-    work = [costs.attn_prefill(run.dims, s.prefill, page)
+    work = [run.arch.attn_prefill(run.dims, s.prefill, page)
             for s in run.window.steps if s.prefill]
     return _kernel_share(run, "paged_attention_prefill", work)
 
